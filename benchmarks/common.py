@@ -78,9 +78,15 @@ def run_mesh_child(module: str, quick: bool, devices: int = 8,
     ``trace_path`` (optional) is exported to the child as the
     ``REPRO_CHILD_TRACE`` env var: children that support cross-process
     collection ``dump_stream`` their recorder there (JSONL + clock
-    handshake) so the parent can ``merge_streams`` onto its timeline."""
+    handshake) so the parent can ``merge_streams`` onto its timeline.
+
+    The child runs on the CPU (``JAX_PLATFORMS=cpu``) whatever the
+    parent runs on: its numbers are forced-host-device measurements by
+    design, and on an accelerator host the parent already holds the
+    device, so a child that asked for it would fail or hang."""
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
                         f" --xla_force_host_platform_device_count={devices}"
                         ).strip()

@@ -14,6 +14,9 @@ The ``fed`` and ``serve`` sections each end with a mesh-scaling
 subsection (``mesh_*`` keys): the shard_map'd engine at 1 vs N forced
 host devices, measured in a subprocess child (the device count must be
 forced before jax initializes) with single-device equivalence asserted.
+The child always runs on the CPU (``JAX_PLATFORMS=cpu``), so the
+``mesh_*`` keys are CPU numbers on whatever host runs the harness, never
+accelerator figures.
 
 Output: CSV lines ``name,us_per_call,derived`` + markdown tables,
 merged into results/bench_results.json.
@@ -346,4 +349,8 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    # the CLI entry only: tests call main() in-process and must not
+    # write a compilation cache
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     sys.exit(main())

@@ -32,6 +32,7 @@ from repro.fed import (AsyncConfig, BufferedAsync, ClientPopulation,
                        ServerConfig, SimConfig, SyncRound, make_cohort_train,
                        run_centralized, run_experiment)
 from repro.fed.simulation import pretrain_backbone
+from repro.launch.compile_cache import enable_compile_cache
 from repro.optim import adamw
 
 
@@ -78,6 +79,7 @@ def run_population(cfg, sim, args):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--task", default="rte", choices=["mrpc", "qqp", "rte"])
     ap.add_argument("--rounds", type=int, default=12)

@@ -15,11 +15,13 @@ import numpy as np
 from repro import checkpoint
 from repro.configs import get_reduced
 from repro.data import make_bigram_lm
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as model_lib
 from repro.optim import adamw, apply_updates, cosine_decay
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gemma-2b")
     ap.add_argument("--steps", type=int, default=200)

@@ -16,11 +16,13 @@ from repro.fed import FedServer, ServerConfig, SimConfig
 from repro.fed.client import (join_adapters, make_cohort_train,
                               split_adapters, split_head)
 from repro.fed.simulation import _stack_client_data, pretrain_backbone
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as model_lib
 from repro.optim import adamw
 
 
 def main():
+    enable_compile_cache()
     cfg = get_reduced("roberta-large")
     sim = SimConfig(task="mrpc", num_examples=1024, rounds=3, local_steps=6,
                     local_batch=16, pretrain_steps=100, lr=1e-3)

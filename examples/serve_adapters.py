@@ -38,6 +38,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_reduced
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as model_lib
 from repro.obs import (MetricsRegistry, Objective, Recorder, SLOMonitor,
                        SeriesStore, snapshot_text, validate_chrome_trace,
@@ -65,6 +66,7 @@ def _fixture():
 
 
 def main():
+    enable_compile_cache()
     cfg, key, params, ranks, adapters, registry = _fixture()
 
     engine = ServeEngine(params, cfg, registry, max_batch=8,

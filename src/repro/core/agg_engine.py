@@ -37,7 +37,6 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import svd as svd_lib
@@ -111,12 +110,7 @@ def _probe_recon_backend(kc: int, d_in: int, r: int, d_out: int,
         # repro: allow=clock-discipline (autotune probe)
         return time.perf_counter() - t0
 
-    try:
-        t_pallas = timed(lambda *xs: ops.recon_agg(*xs))
-    except Exception:                          # kernel unsupported here
-        _AUTOTUNE_CACHE[key] = False
-        return False
-    decision = t_pallas < timed(ref_fn)
+    decision = timed(ops.recon_agg) < timed(ref_fn)
     _AUTOTUNE_CACHE[key] = decision
     return decision
 
@@ -141,12 +135,12 @@ def _masked(a, b, mask):
 
 def _dense_update(a, b, mask, eta, alpha, *, use_pallas: bool) -> jax.Array:
     """ΔW' = Σ_k coef_k (A_k·m_k)(B_k·m_k) — Eq. 2, dense form."""
+    from repro.kernels import ops, ref
     coef = _coefficients(mask, eta, alpha)
     am, bm = _masked(a, b, mask)
     if use_pallas:
-        from repro.kernels import ops
         return ops.recon_agg(am, bm, coef)
-    return jnp.einsum("k,kir,kro->io", coef, am, bm)
+    return ref.recon_agg_ref(am, bm, coef)
 
 
 def _factored_update(a, b, mask, eta, alpha) -> Tuple[jax.Array, jax.Array]:
@@ -245,12 +239,14 @@ class AggregationEngine:
         entirely on one device, so the sharded path evaluates the exact
         same per-item op sequence as the single-device path (equivalence
         pinned in tests). Batches that don't divide the device count are
-        tile-padded with leading items (valid data, sliced off after)."""
+        tile-padded with leading items (valid data, sliced off after).
+        The engine runs on the mesh's Auto-axis view
+        (``mesh_lib.auto_axes``), so a mesh with Explicit axes works too."""
         self._jitted: Dict[tuple, callable] = {}
         self.trace_count = 0   # incremented at trace time only
         self.use_pallas = use_pallas
         self.factored_impl = factored_impl
-        self.mesh = mesh
+        self.mesh = mesh_lib.auto_axes(mesh)
 
     # -- public entry -------------------------------------------------------
 
@@ -384,7 +380,12 @@ class AggregationEngine:
         ndev = mesh_lib.data_axis_size(self.mesh)
         if ndev <= 1:
             return vmapped(ab, bb, mb, nmb, eta, alpha, keys)
-        pad = (-batch) % ndev
+        # Every device holds at least two items whenever the batch has
+        # two: XLA drops a size-1 batch dim and may then lower a dot in a
+        # different summation order, so a one-item shard would not be
+        # bit-identical to the batched single-device program.
+        per_dev = max(-(-batch // ndev), min(batch, 2))
+        pad = per_dev * ndev - batch
         if pad:
             # Tile-pad with leading items: real data (zero-padding would
             # push rank-0 garbage through Cholesky), sliced off below.
@@ -404,13 +405,13 @@ class AggregationEngine:
             return P(*((None,) * jnp.ndim(x)))
 
         a_sh = jax.eval_shape(vmapped, ab, bb, mb, nmb, eta, alpha, keys)
-        fn = shard_map(
+        fn = jax.shard_map(
             vmapped, mesh=self.mesh,
             in_specs=(bspec(ab), bspec(bb), bspec(mb), bspec(nmb),
                       rspec(eta), rspec(alpha), bspec(keys)),
             out_specs=jax.tree.map(bspec, a_sh),
             # eigh/cholesky custom calls carry no replication rule
-            check_rep=False)
+            check_vma=False)
         a_o, b_o, s = fn(ab, bb, mb, nmb, eta, alpha, keys)
         if pad:
             a_o, b_o, s = a_o[:batch], b_o[:batch], s[:batch]

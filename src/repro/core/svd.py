@@ -25,6 +25,27 @@ import jax
 import jax.numpy as jnp
 
 
+@jax.custom_batching.custom_vmap
+def _svd_small(x: jax.Array) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Thin SVD of one small matrix, taken item by item under ``vmap``.
+
+    On the TPU, ``jnp.linalg.svd`` of a batch of small matrices gives an
+    item other bits depending on which items share its batch: on a v5e,
+    every item of a batch of 48 32x32 matrices differed (by up to 3e-5)
+    from the same item in a batch of 12 or of 2, while the Gram products,
+    Cholesky QR and eigh around it did not. The sharded aggregation
+    engine, whose devices each hold a slice of the batch, then drifts
+    from the single-device one. Mapped one item at a time, each result
+    depends on its own matrix only."""
+    return tuple(jnp.linalg.svd(x, full_matrices=False))
+
+
+@_svd_small.def_vmap
+def _svd_small_vmap(axis_size, in_batched, x):
+    del axis_size, in_batched
+    return jax.lax.map(_svd_small, x), (True, True, True)
+
+
 def svd_exact(w: jax.Array, r: int) -> Tuple[jax.Array, jax.Array, jax.Array]:
     u, s, vt = jnp.linalg.svd(w, full_matrices=False)
     return u[..., :, :r], s[..., :r], vt[..., :r, :]
@@ -42,7 +63,7 @@ def svd_factored(
     qp, rp = jnp.linalg.qr(p, mode="reduced")          # (d_in,R), (R,R)
     qq, rq = jnp.linalg.qr(q.T, mode="reduced")        # (d_out,R), (R,R)
     core = rp @ rq.T                                    # (R,R)
-    uu, s, vvt = jnp.linalg.svd(core, full_matrices=False)
+    uu, s, vvt = _svd_small(core)
     u = qp @ uu
     vt = (qq @ vvt.T).T
     return u[:, :r], s[:r], vt[:r, :]
@@ -110,7 +131,7 @@ def svd_factored_gram(
     rinv_p, rp = _cholqr2(p, shift)
     rinv_q, rq = _cholqr2(q.T, shift)
     core = rp @ rq.T                                      # (R, R)
-    uu, s, vvt = jnp.linalg.svd(core, full_matrices=False)
+    uu, s, vvt = _svd_small(core)
     u = p @ (rinv_p @ uu[:, :r])                          # Qp Û_r, thin
     vt = (q.T @ (rinv_q @ vvt.T[:, :r])).T                # (Qq V̂_r)ᵀ, thin
     return u, s[:r], vt
@@ -144,7 +165,7 @@ def svd_randomized(
     y, _ = jax.lax.scan(body, y, None, length=iters)
     q, _ = jnp.linalg.qr(y, mode="reduced")             # (d_in, l)
     b = q.T @ w                                         # (l, d_out)
-    ub, s, vt = jnp.linalg.svd(b, full_matrices=False)
+    ub, s, vt = _svd_small(b)
     u = q @ ub
     return u[:, :r], s[:r], vt[:r, :]
 

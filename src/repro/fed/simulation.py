@@ -128,10 +128,15 @@ def make_experiment_setup(cfg: ModelConfig, sim: SimConfig,
     local_train = jax.jit(make_local_train(cfg, opt))
 
     @jax.jit
-    def eval_fn(lora_tree, head):
+    def _eval(frozen, lora_tree, head):
         params = {**frozen, **head, "lora": lora_tree}
         _, m = model_lib.loss_fn(params, ev_batch, cfg, remat=False)
         return m
+
+    def eval_fn(lora_tree, head):
+        # the backbone goes in as an argument: closed over, it would be
+        # baked into the executable as a constant
+        return _eval(frozen, lora_tree, head)
 
     def data_fn(cohort, rnd):
         return _stack_client_data(tokens, labels, shards, cohort, sim, rnd)
@@ -218,7 +223,7 @@ def run_centralized(
     history = {"round": [], "train_loss": [], "eval_acc": [], "eval_loss": []}
 
     @jax.jit
-    def eval_fn(trainable):
+    def eval_fn(frozen, trainable):
         params = {**frozen, **trainable["head"],
                   "lora": join_adapters(trainable["factors"], masks)}
         _, m = model_lib.loss_fn(params, ev_batch, cfg, remat=False)
@@ -230,7 +235,7 @@ def run_centralized(
         data = {"tokens": jnp.asarray(tokens[picks]),
                 "labels": jnp.asarray(labels[picks])}
         trainable, loss = local(frozen, trainable, masks, data)
-        m = eval_fn(trainable)
+        m = eval_fn(frozen, trainable)
         history["round"].append(rnd)
         history["train_loss"].append(float(loss))
         history["eval_acc"].append(float(m["acc"]))

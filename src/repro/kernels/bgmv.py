@@ -12,8 +12,11 @@ before the body runs) so the BlockSpec index maps steer the DMA engine
 directly at A[idx[i]] / B[idx[i]] — the gather costs nothing beyond the
 loads the matmul needs anyway, and rows sharing an adapter hit the same
 HBM tiles. Grid (B, d_out/bn): one request row per program, the output
-dim tiled so a (1, R)·(R, bn) MXU pass closes each tile. The (1, d_in)
-row block is sublane-padded by Mosaic; per-row VMEM footprint is
+dim tiled so a (1, R)·(R, bn) MXU pass closes each tile. x and the
+output are viewed as (B, 1, d) with the row dim squeezed from the
+blocks: a (1, d_in) block of a (B, d_in) array would break Mosaic's rule
+that a block's second-to-last dim be a multiple of 8 or the whole array
+dim, while (1, d_in) of (B, 1, d_in) spans it. Per-row VMEM footprint is
 (d_in·R + R·bn)·4B — ~1 MB at gemma-2b scale (d=2048, R=128), far under
 the ~16 MB budget. All of d_in/d_out/R must be lane-aligned (128);
 the ops.py wrapper zero-pads and slices back.
@@ -26,8 +29,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.kernels.compat import CompilerParams
 
 
 def _kernel(idx_ref, x_ref, a_ref, b_ref, o_ref):
@@ -53,19 +54,21 @@ def bgmv(x, a, b, idx, *, block_n: int = 256, interpret: bool = False):
         num_scalar_prefetch=1,
         grid=(bsz, d_out // bn),
         in_specs=[
-            pl.BlockSpec((1, d_in), lambda i, j, idx_ref: (i, 0)),       # x
+            pl.BlockSpec((None, 1, d_in),
+                         lambda i, j, idx_ref: (i, 0, 0)),               # x
             pl.BlockSpec((1, d_in, r),
                          lambda i, j, idx_ref: (idx_ref[i], 0, 0)),      # A
             pl.BlockSpec((1, r, bn),
                          lambda i, j, idx_ref: (idx_ref[i], 0, j)),      # B
         ],
-        out_specs=pl.BlockSpec((1, bn), lambda i, j, idx_ref: (i, j)),
+        out_specs=pl.BlockSpec((None, 1, bn),
+                               lambda i, j, idx_ref: (i, 0, j)),
     )
     return pl.pallas_call(
         _kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((bsz, d_out), x.dtype),
-        compiler_params=CompilerParams(
+        out_shape=jax.ShapeDtypeStruct((bsz, 1, d_out), x.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "parallel")),
         interpret=interpret,
-    )(idx.astype(jnp.int32), x, a, b)
+    )(idx.astype(jnp.int32), x[:, None, :], a, b)[:, 0, :]

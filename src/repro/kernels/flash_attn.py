@@ -25,7 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 
 NEG_INF = -1e30
 
@@ -115,7 +114,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window=None,
             kv_steps=skv // bk, block_q=bq, block_k=bk),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((h, sq, d), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qoff, qt, kt, vt)

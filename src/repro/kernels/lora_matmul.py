@@ -21,8 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 
 def _kernel(x_ref, w0_ref, a_ref, b_ref, o_ref, acc_ref, xa_ref, *,
             scale: float, k_steps: int):
@@ -74,7 +72,7 @@ def lora_matmul(x, w0, a, b, scale: float = 1.0, *, block_m: int = 256,
             pltpu.VMEM((bm, bn), jnp.float32),
             pltpu.VMEM((bm, r), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, w0, a, b)

@@ -17,8 +17,10 @@ def lora_matmul_ref(x: jax.Array, w0: jax.Array, a: jax.Array, b: jax.Array,
 
 def recon_agg_ref(a: jax.Array, b: jax.Array, eta: jax.Array) -> jax.Array:
     """W' = Σ_k η_k · A_k B_k.
-    a: (Kc, d_in, r), b: (Kc, r, d_out), eta: (Kc,)."""
-    return jnp.einsum("k,kir,kro->io", eta, a, b)
+    a: (Kc, d_in, r), b: (Kc, r, d_out), eta: (Kc,). Full f32 products,
+    as in the kernel: the TPU's default would round the factors to bf16."""
+    return jnp.einsum("k,kir,kro->io", eta, a, b,
+                      precision=jax.lax.Precision.HIGHEST)
 
 
 def bgmv_ref(x: jax.Array, a: jax.Array, b: jax.Array, idx: jax.Array

@@ -42,7 +42,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 
 NEG_INF = -1e30
 
@@ -144,7 +143,7 @@ def paged_verify_attention(q, k_pool, v_pool, page_tables, lengths,
                           groups=g),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((bsz, sq, hkv, g, dh), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "parallel", "arbitrary")),
         interpret=interpret,
     )(page_tables.astype(jnp.int32), lengths.astype(jnp.int32),

@@ -10,6 +10,7 @@ jax device state (the dry-run sets XLA_FLAGS before first jax init).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType, Mesh
 
 # Hardware constants for the roofline analysis (TPU v5e).
 PEAK_FLOPS_BF16 = 197e12       # per chip
@@ -26,7 +27,7 @@ def make_production_mesh(*, multi_pod: bool = False, shape=None):
         shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = (("pod", "data", "model") if len(shape) == 3
             else ("data", "model"))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, (AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
@@ -49,7 +50,23 @@ def make_host_mesh(data: int = 1, model: int = 1):
             f"only {avail} exist — set XLA_FLAGS="
             f"--xla_force_host_platform_device_count={data * model} "
             f"before the first jax call")
-    return jax.make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         (AxisType.Auto,) * 2)
+
+
+def auto_axes(mesh):
+    """``mesh`` with every axis of type Auto (``None`` stays ``None``).
+
+    ``jax.make_mesh`` gives Explicit axes by default, and with them every
+    array a jitted body touches carries its sharding in its type: a
+    reshape or slice of a sharded dim that the type cannot describe is
+    refused. The engines shard one batch axis with ``jax.shard_map`` and
+    let the compiler place everything else, so they run on the Auto view
+    of whatever mesh a caller built — same devices, same axis names."""
+    if mesh is None or all(t == AxisType.Auto for t in mesh.axis_types):
+        return mesh
+    return Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
 
 
 def data_axis_size(mesh) -> int:

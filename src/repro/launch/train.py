@@ -22,10 +22,12 @@ from repro import checkpoint
 from repro.configs import get_config, get_reduced
 from repro.fed import ServerConfig, SimConfig, run_centralized, run_experiment
 from repro.fed.simulation import pretrain_backbone
+from repro.launch.compile_cache import enable_compile_cache
 from repro.util import atomic_write_json
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="roberta-large")
     ap.add_argument("--full-config", action="store_true")

@@ -111,8 +111,10 @@ def attention(
             mask &= kv_valid[:, None, :]
         logits = jnp.where(mask[:, None, :, :], logits, -1e30)
         p = jax.nn.softmax(logits, axis=-1)
-        out = jnp.einsum("bhcs,bshd->bchd", p.astype(v.dtype), v)
-        return out
+        # probabilities stay f32 against an f32 view of v, as in the
+        # flash and paged kernels; only the output takes v's dtype
+        out = jnp.einsum("bhcs,bshd->bchd", p, v.astype(jnp.float32))
+        return out.astype(v.dtype)
 
     if sq <= q_chunk:
         return attend_chunk(q, q_offset + jnp.arange(sq))

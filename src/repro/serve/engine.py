@@ -57,7 +57,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig
@@ -347,7 +346,7 @@ class ServeEngine:
                 "draft-verify path")
         if drafter is not None and spec_k < 1:
             raise ValueError(f"spec_k must be >= 1, got {spec_k}")
-        self.mesh = mesh
+        self.mesh = mesh_lib.auto_axes(mesh)
         self.num_shards = mesh_lib.data_axis_size(mesh)
         if self.num_shards > 1 and kv_mode != "paged":
             raise ValueError(
@@ -533,7 +532,7 @@ class ServeEngine:
         uses; pools split on the page axis; params/slabs replicate.
         Per-row compute touches nothing across rows, so no collectives —
         each device runs the identical single-device step on its block
-        (``check_rep=False``: replication inference has no rule for the
+        (``check_vma=False``: replication inference has no rule for the
         linalg/gather custom calls inside).
 
         Prefill is the one replicated-compute step: every device runs
@@ -550,15 +549,15 @@ class ServeEngine:
         rep = P()
         pool = shard_rules.page_pool_pspec(self.mesh)
         step = self._wrap_decode_shaped(self._paged_step_impl)
-        verify = shard_map(
+        verify = jax.shard_map(
             self._verify_impl, mesh=self.mesh,
             in_specs=(rep, rep, pool, row(2), row(1), row(2), row(1),
                       row(1)),
-            out_specs=(row(3), pool), check_rep=False)
-        prefill = shard_map(
+            out_specs=(row(3), pool), check_vma=False)
+        prefill = jax.shard_map(
             self._prefill_impl, mesh=self.mesh,
             in_specs=(rep, rep, pool, row(2), row(1), rep, rep, rep),
-            out_specs=(row(2), pool), check_rep=False)
+            out_specs=(row(2), pool), check_vma=False)
         return step, verify, prefill
 
     def _wrap_decode_shaped(self, impl):
@@ -576,11 +575,11 @@ class ServeEngine:
 
         rep = P()
         pool = shard_rules.page_pool_pspec(self.mesh)
-        return shard_map(
+        return jax.shard_map(
             impl, mesh=self.mesh,
             in_specs=(rep, rep, pool, row(2), row(1), row(2), row(1),
                       row(1)),
-            out_specs=(row(2), pool), check_rep=False)
+            out_specs=(row(2), pool), check_vma=False)
 
     # -- jitted bodies ------------------------------------------------------
 

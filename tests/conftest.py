@@ -5,16 +5,6 @@ import pytest
 # dry-run, forces 512 placeholder devices — see launch/dryrun.py).
 jax.config.update("jax_enable_x64", False)
 
-# Property tests use hypothesis when available; the runtime image does not
-# ship it, so fall back to a deterministic stub (same API surface, fixed
-# RNG) rather than failing collection. See tests/_hypothesis_stub.py and
-# requirements-dev.txt.
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    import _hypothesis_stub
-    _hypothesis_stub.install()
-
 
 @pytest.fixture(scope="session")
 def rng_key():
@@ -29,9 +19,12 @@ def host_mesh_env():
     the process's first jax device query, so the 8-device mesh tests
     (tests/test_mesh.py) run in a child pytest marked by
     ``REPRO_MESH_CHILD`` — the rest of tier-1 keeps the single default
-    device and is completely unaffected."""
+    device and is completely unaffected. The child is pinned to the CPU:
+    forced host devices are what it tests, and an accelerator would
+    already be held by this process."""
     import os
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                         + " --xla_force_host_platform_device_count=8").strip()
     env["REPRO_MESH_CHILD"] = "1"

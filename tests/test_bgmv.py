@@ -1,9 +1,4 @@
-"""BGMV kernel property tests against the jnp oracle (interpret mode).
-
-Stays inside the hypothesis-stub API subset (``given`` with keyword
-``integers``/``sampled_from`` strategies — see tests/_hypothesis_stub.py)
-so the properties run with or without real hypothesis installed.
-"""
+"""BGMV kernel property tests against the jnp oracle (interpret mode)."""
 import jax
 import jax.numpy as jnp
 import numpy as np

@@ -55,7 +55,7 @@ def _roundtrip(codec, adapter):
 # Property tests: quantization error bounds / top-k exactness
 # ---------------------------------------------------------------------------
 
-@settings(max_examples=10)
+@settings(max_examples=10, deadline=None)
 @given(seed=st.integers(0, 1000), r=st.integers(1, 8),
        layers=st.integers(1, 3))
 def test_int8_error_bounded_by_half_scale(seed, r, layers):
@@ -71,7 +71,7 @@ def test_int8_error_bounded_by_half_scale(seed, r, layers):
             assert err.max() <= scale / 2 + 1e-7, (t, leaf)
 
 
-@settings(max_examples=10)
+@settings(max_examples=10, deadline=None)
 @given(seed=st.integers(0, 1000), r=st.integers(1, 8))
 def test_bf16_relative_error_bounded(seed, r):
     adapter = _adapter(seed, r=r)
@@ -82,7 +82,7 @@ def test_bf16_relative_error_bounded(seed, r):
             assert (err <= 2.0 ** -8 * np.abs(ad[leaf]) + 1e-12).all()
 
 
-@settings(max_examples=10)
+@settings(max_examples=10, deadline=None)
 @given(seed=st.integers(0, 1000), r=st.integers(1, 8), k=st.integers(1, 10))
 def test_topk_kept_directions_exact_dropped_zero(seed, r, k):
     adapter = _adapter(seed, r=r)

@@ -397,7 +397,6 @@ def test_bgmv_batch_align_per_shard_odd_batch():
     result round-trips exactly to the unsharded call."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.kernels import ops
@@ -414,10 +413,10 @@ def test_bgmv_batch_align_per_shard_odd_batch():
 
     want = ops.bgmv(x, a, bb, idx)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda x_, i_: ops.bgmv(x_, a, bb, i_, batch_align=4),
         mesh=mesh, in_specs=(P("data"), P("data")),
-        out_specs=P("data"), check_rep=False)
+        out_specs=P("data"), check_vma=False)
     got = jax.jit(fn)(x, idx)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-6)
@@ -446,3 +445,25 @@ def test_paged_attention_batch_align_odd_batch():
     aligned = ops.paged_attention(q, k_pool, v_pool, tables, lengths,
                                   page_size=ps, batch_align=8)
     np.testing.assert_array_equal(np.asarray(base), np.asarray(aligned))
+
+
+# ---------------------------------------------------------------------------
+# Child-side: chip_smoke.py's four-chip phases at reduced widths
+# ---------------------------------------------------------------------------
+
+@child
+def test_chip_smoke_mesh_phases():
+    """``chip_smoke.py --chips 4`` runs these two phases on a (4, 1)
+    mesh: sharded aggregation bit-identical to one device (full tree and
+    a batch that does not divide the mesh), sharded serving token-
+    identical to one device with the KV pools on every device."""
+    import chip_smoke
+    from repro.configs import get_reduced
+    from repro.launch.mesh import make_host_mesh
+
+    mesh = make_host_mesh(data=4)
+    agg = chip_smoke.mesh_agg_phase(get_reduced("roberta-large"), mesh)
+    assert agg["odd"]["batch"] % 4 and agg["full"]["bit_identical"]
+    serve = chip_smoke.mesh_serve_phase(get_reduced("gemma-2b"), mesh,
+                                        prompt_len=8, new_tokens=4)
+    assert serve["token_identical"] == "32/32"

@@ -1,9 +1,5 @@
 """Paged-KV subsystem tests: allocator invariants + kernel equivalence.
 
-Property tests stay inside the hypothesis-stub API subset (``given``
-with keyword ``integers``/``sampled_from`` strategies — see
-tests/_hypothesis_stub.py) so they run with or without real hypothesis.
-
 The allocator invariants under test are the ones the serving scheduler
 leans on: conservation (every page free or owned by exactly one owner),
 no double-use, failed alloc/extend leave state untouched, pinned owners

@@ -3,8 +3,7 @@
 Drives the real engine on the reduced gemma config — batched
 heterogeneous-rank multi-LoRA decode vs the per-request merged-weight
 oracle, continuous batching with row recycling, and retrace-free
-hot-swap. This is the test that would have caught the PR-1
-``TPUCompilerParams`` API drift before it reached main.
+hot-swap.
 
 The engine defaults to the paged KV cache with chunked prefill
 (PR 3), so these tests pin that path; the retained dense ring cache is

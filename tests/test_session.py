@@ -135,8 +135,10 @@ def _legacy_run_experiment(cfg, sim, scfg, base_params):
                               client_sizes=[len(s) for s in shards])
     cohort_train = make_cohort_train(cfg, adamw(sim.lr))
 
+    # the backbone is an argument, as in make_experiment_setup: a closed-
+    # over one is folded in as a constant and rounds differently
     @jax.jit
-    def eval_fn(lora_tree, head):
+    def eval_fn(frozen, lora_tree, head):
         params = {**frozen, **head, "lora": lora_tree}
         _, m = model_lib.loss_fn(params, ev_batch, cfg, remat=False)
         return m
@@ -155,7 +157,7 @@ def _legacy_run_experiment(cfg, sim, scfg, base_params):
                              cohort, stacked_heads=trainable["head"])
         history["round"].append(rnd)
         history["train_loss"].append(float(jnp.mean(losses)))
-        m = eval_fn(server.global_lora, server.global_head)
+        m = eval_fn(frozen, server.global_lora, server.global_head)
         history["eval_acc"].append(float(m["acc"]))
         history["eval_loss"].append(float(m["loss"]))
     return history
@@ -200,7 +202,7 @@ def _payload(seed, layers, d_in, d_out, r, dtype):
     return {"q": {"A": a, "B": b}}
 
 
-@settings(max_examples=12)
+@settings(max_examples=12, deadline=None)
 @given(r=st.integers(1, 8), layers=st.integers(1, 3),
        dtype=st.sampled_from(["f32", "bf16"]),
        kind=st.sampled_from(["broadcast", "update"]))

@@ -69,3 +69,20 @@ def test_truncation_error_decreases_with_rank():
         errs.append(float(svd.truncation_error(w, a, b)))
     assert errs == sorted(errs, reverse=True)
     assert errs[-1] < 1e-5  # full rank => exact
+
+
+@pytest.mark.parametrize("batch", [(5,), (2, 3)], ids=["vmap", "vmap2"])
+def test_small_svd_batched_is_per_item(batch):
+    """Under vmap the small SVD takes one item at a time, so an item's
+    result is the unbatched call's, whatever shares its batch."""
+    x = jax.random.normal(jax.random.PRNGKey(3), (*batch, 12, 12))
+    fn = svd._svd_small
+    for _ in batch:
+        fn = jax.vmap(fn)
+    got = jax.jit(fn)(x)
+    flat = x.reshape(-1, 12, 12)
+    for i in range(flat.shape[0]):
+        want = jax.jit(svd._svd_small)(flat[i])
+        for g, w in zip(got, want):
+            np.testing.assert_array_equal(
+                np.asarray(g).reshape(-1, *w.shape)[i], np.asarray(w))
