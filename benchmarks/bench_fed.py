@@ -97,18 +97,6 @@ def run(quick: bool = False) -> Dict:
     out["obs_uplink_bytes_per_round"] = \
         metrics.counter("fed.uplink_bytes").value / nr
     out["obs_events"] = len(rec)
-    # per-round health snapshots (observe-only): every scheduler round
-    # appended one; anomalies stay 0 on this steady workload
-    out["obs_health_rounds"] = len(h["health"])
-    out["obs_health_anomalies"] = float(
-        metrics.counter("fed.health.anomalies").value)
-    out["obs_health_stragglers"] = float(
-        sum(s["stragglers"] for s in h["health"]))
-    assert out["obs_health_rounds"] == sim.rounds
-    emit("fed/obs_health", 0.0,
-         f"{out['obs_health_rounds']} round snapshots, "
-         f"anomalies={out['obs_health_anomalies']:.0f}, "
-         f"staleness_p99[last]={h['health'][-1]['staleness_p99']:.1f}")
     emit("fed/obs_rounds", rs.get("p50", 0.0) * 1e3,
          f"round p50={out['obs_round_ms_p50']:.0f}ms "
          f"p99={out['obs_round_ms_p99']:.0f}ms, bytes/round=down:"

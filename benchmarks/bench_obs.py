@@ -48,7 +48,7 @@ from repro.serve.oracle import make_demo_adapter
 _SLO_OBJECTIVES = (
     Objective("serve_ttft", series="first_token.ttft_s",
               threshold=60.0, target=0.9),
-    Objective("fed_aggregate", series="span.aggregate",
+    Objective("fed_aggregate", series="span.fed.aggregate",
               threshold=60.0, target=0.9),
 )
 
@@ -97,8 +97,6 @@ def _fed_half(rec: Recorder, metrics: MetricsRegistry, results: Dict):
                                           heads if heads else None)
     sess.aggregate_round(tree, cohort, stacked_heads=up_heads)
     results["obs_fed_rounds"] = sess.rounds_done
-    results["obs_fed_health_anomalies"] = \
-        sess.health_snapshot()["anomalies"]
     results["obs_fed_downlink_bytes"] = \
         metrics.counter("fed.downlink_bytes").value
 
@@ -110,7 +108,7 @@ def _watch(rec: Recorder, metrics: MetricsRegistry, results: Dict):
     store.fold(rec.events())
     results["obs_series"] = len(store.names())
     assert store.has("first_token.ttft_s"), "TTFT series missing"
-    assert store.has("span.aggregate"), "aggregate span series missing"
+    assert store.has("span.fed.aggregate"), "aggregate span series missing"
 
     slo = SLOMonitor(list(_SLO_OBJECTIVES), recorder=rec)
     slo.fold(rec.events())
@@ -170,8 +168,8 @@ def run(quick: bool = False) -> Dict:
     results["obs_jsonl_roundtrip"] = 1
 
     names = {e[1] for e in rec.events()}
-    for want in ("submit", "prefill_chunk", "decode_step", "finish",
-                 "broadcast", "collect", "aggregate"):
+    for want in ("submit", "serve.prefill_chunk", "serve.decode_step",
+                 "finish", "fed.broadcast", "fed.collect", "fed.aggregate"):
         assert want in names, f"missing {want!r} events in the trace"
     results["obs_span_names_ok"] = 1
     results["obs_tracks"] = len({e[2] for e in rec.events()})

@@ -262,8 +262,9 @@ def observability():
     counts = validate_chrome_trace(doc)
     n = write_jsonl(rec.events(), "results/serve_events.jsonl")
     names = {e[1] for e in rec.events()}
-    covered = [s for s in ("prefill_chunk", "decode_step", "draft",
-                           "verify_step", "preempt", "replay", "defer")
+    covered = [s for s in ("serve.prefill_chunk", "serve.decode_step",
+                           "serve.draft", "serve.verify_step", "preempt",
+                           "replay", "defer")
                if s in names]
     print(f"\nobservability: {n} events ({counts['X']} spans) on "
           f"{len({e[2] for e in rec.events()})} tracks -> "
@@ -280,7 +281,7 @@ def observability():
     slo = SLOMonitor([
         Objective("ttft", series="first_token.ttft_s", threshold=60.0,
                   target=0.9),
-        Objective("decode", series="span.decode_step", threshold=60.0,
+        Objective("decode", series="span.serve.decode_step", threshold=60.0,
                   target=0.9)], recorder=rec)
     slo.fold(rec.events())
     write_html("results/serve_report.html",

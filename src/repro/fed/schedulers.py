@@ -28,6 +28,19 @@ accounting). Three policies:
 
 All schedulers share the session's redistribution path, so spectrum and
 per-target rank adaptation work in every mode.
+
+A cohort round records this span tree (``fed/session.py`` names the
+tracks; each span also reaches a running profiler capture)::
+
+    fed.round                        whole round, index as an argument
+      fed.broadcast                  fed.redistribute, fed.downlink,
+                                     fed.restack
+      fed.data                       the cohort's batches (data_fn)
+      fed.train                      the trainer's dispatch only: its
+                                     device time is read from the trace
+      fed.collect                    fed.uplink, fed.restack
+      fed.aggregate                  merge dispatch + head FedAvg
+      fed.close                      loss read-back (blocks) + history
 """
 from __future__ import annotations
 
@@ -39,11 +52,17 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.fed.client import join_adapters, split_adapters
-from repro.fed.session import AsyncConfig
+from repro.fed.session import AsyncConfig, ROUNDS_TRACK, SERVER_TRACK
 
 
 class Scheduler:
     name = "base"
+
+
+def _wire_rows(history, session) -> None:
+    """The round's measured downlink and uplink bytes."""
+    history["downlink_bytes"].append(session.comm_log["downlink"][-1])
+    history["uplink_bytes"].append(session.comm_log["uplink"][-1])
 
 
 def _eval_round(history, session, eval_fn, do_eval: bool) -> None:
@@ -77,40 +96,37 @@ class SyncRound(Scheduler):
         continues the round index from ``session.rounds_done``."""
         history: Dict[str, List] = {
             "round": [], "train_loss": [], "eval_acc": [], "eval_loss": [],
-            "downlink_bytes": [], "uplink_bytes": [], "health": []}
+            "downlink_bytes": [], "uplink_bytes": []}
         rec = session.rec
         for i in range(num_rounds):
             rnd = session.rounds_done
-            t_rnd = rec.now() if rec.enabled else 0.0
-            cohort = session.sample_cohort()
-            stacked, heads = session.broadcast_cohort(cohort)
-            factors, masks = split_adapters(stacked)
-            trainable = {"factors": factors, "head": heads}
-            t_tr = rec.now() if rec.enabled else 0.0
-            trainable, losses = train(session.base, trainable, masks,
-                                      data_fn(cohort, rnd))
+            with rec.span("fed.round", ROUNDS_TRACK, round=rnd) as whole:
+                cohort = session.sample_cohort()
+                stacked, heads = session.broadcast_cohort(cohort)
+                factors, masks = split_adapters(stacked)
+                trainable = {"factors": factors, "head": heads}
+                with rec.span("fed.data", SERVER_TRACK, round=rnd):
+                    batches = data_fn(cohort, rnd)
+                with rec.span("fed.train", SERVER_TRACK, round=rnd,
+                              cohort=len(cohort)):
+                    trainable, losses = train(session.base, trainable,
+                                              masks, batches)
+                trained = join_adapters(trainable["factors"], masks)
+                if self.topology is not None:
+                    self.topology.aggregate(session, cohort, trained,
+                                            trainable["head"])
+                else:
+                    tree, up_heads = session.collect_updates(
+                        cohort, trained, trainable["head"])
+                    session.aggregate_round(tree, cohort,
+                                            stacked_heads=up_heads)
+                with rec.span("fed.close", SERVER_TRACK, round=rnd):
+                    history["round"].append(rnd)
+                    history["train_loss"].append(float(jnp.mean(losses)))
+                    _wire_rows(history, session)
             if rec.enabled:
-                rec.complete("train", "fed.train", t_tr, rec.now(),
-                             round=rnd, cohort=len(cohort))
-            trained = join_adapters(trainable["factors"], masks)
-            if self.topology is not None:
-                self.topology.aggregate(session, cohort, trained,
-                                        trainable["head"])
-            else:
-                tree, up_heads = session.collect_updates(
-                    cohort, trained, trainable["head"])
-                session.aggregate_round(tree, cohort,
-                                        stacked_heads=up_heads)
-            if rec.enabled:
-                t1 = rec.now()
-                rec.complete(f"round{rnd}", "fed.rounds", t_rnd, t1,
-                             cohort=len(cohort))
-                session.metrics.histogram("fed.round_s").observe(t1 - t_rnd)
-            history["round"].append(rnd)
-            history["train_loss"].append(float(jnp.mean(losses)))
-            history["downlink_bytes"].append(session.comm_log["downlink"][-1])
-            history["uplink_bytes"].append(session.comm_log["uplink"][-1])
-            history["health"].append(session.health_snapshot())
+                session.metrics.histogram("fed.round_s").observe(
+                    whole.seconds)
             _eval_round(history, session, eval_fn,
                         rnd % eval_every == 0 or i == num_rounds - 1)
         return history
@@ -138,64 +154,61 @@ class SemiSync(Scheduler):
         history: Dict[str, List] = {
             "round": [], "train_loss": [], "eval_acc": [], "eval_loss": [],
             "downlink_bytes": [], "uplink_bytes": [], "stragglers": [],
-            "round_time": [], "health": []}
+            "round_time": []}
         rec = session.rec
         for i in range(num_rounds):
             rnd = session.rounds_done
-            t_rnd = rec.now() if rec.enabled else 0.0
-            cohort = session.sample_cohort()
-            durations = 1.0 / speeds[cohort]
-            keep = durations <= deadline
-            if not keep.any():                 # never stall a round
-                keep[np.argmin(durations)] = True
-            if rec.enabled and not keep.all():
-                rec.instant("deadline_cut", "fed.rounds", round=rnd,
-                            stragglers=int((~keep).sum()),
-                            deadline=deadline)
-            stacked, heads = session.broadcast_cohort(cohort)
-            factors, masks = split_adapters(stacked)
-            trainable = {"factors": factors, "head": heads}
-            t_tr = rec.now() if rec.enabled else 0.0
-            trainable, losses = train(session.base, trainable, masks,
-                                      data_fn(cohort, rnd))
+            with rec.span("fed.round", ROUNDS_TRACK, round=rnd) as whole:
+                cohort = session.sample_cohort()
+                durations = 1.0 / speeds[cohort]
+                keep = durations <= deadline
+                if not keep.any():                 # never stall a round
+                    keep[np.argmin(durations)] = True
+                cut = int((~keep).sum())
+                if rec.enabled and cut:
+                    rec.instant("deadline_cut", ROUNDS_TRACK, round=rnd,
+                                stragglers=cut, deadline=deadline)
+                stacked, heads = session.broadcast_cohort(cohort)
+                factors, masks = split_adapters(stacked)
+                trainable = {"factors": factors, "head": heads}
+                with rec.span("fed.data", SERVER_TRACK, round=rnd):
+                    batches = data_fn(cohort, rnd)
+                with rec.span("fed.train", SERVER_TRACK, round=rnd,
+                              cohort=len(cohort)):
+                    trainable, losses = train(session.base, trainable,
+                                              masks, batches)
+                trained = join_adapters(trainable["factors"], masks)
+                idx = np.flatnonzero(keep)
+                sub_tree = {t: {leaf: ad[leaf][idx]
+                                for leaf in ("A", "B", "mask")}
+                            for t, ad in trained.items()}
+                sub_heads = None if not trainable["head"] else {
+                    k: v[idx] for k, v in trainable["head"].items()}
+                tree, up_heads = session.collect_updates(
+                    cohort[idx], sub_tree, sub_heads)
+                session.aggregate_round(tree, cohort[idx],
+                                        stacked_heads=up_heads)
+                with rec.span("fed.close", SERVER_TRACK, round=rnd):
+                    history["round"].append(rnd)
+                    history["train_loss"].append(
+                        float(jnp.mean(jnp.asarray(losses)[idx])))
+                    _wire_rows(history, session)
+                    history["stragglers"].append(cut)
+                    session.metrics.counter("fed.stragglers").inc(cut)
+                    # the server closes the round when every survivor is
+                    # in: at durations.max() if nobody was cut, else at
+                    # the deadline — unless the force-kept fastest itself
+                    # finishes after it
+                    round_time = (
+                        float(durations.max()) if keep.all()
+                        else float(max(deadline, durations[keep].max())))
+                    history["round_time"].append(round_time)
+                    # simulated time, no clock read: always on
+                    session.metrics.histogram("fed.round_time_sim").observe(
+                        round_time)
             if rec.enabled:
-                rec.complete("train", "fed.train", t_tr, rec.now(),
-                             round=rnd, cohort=len(cohort))
-            trained = join_adapters(trainable["factors"], masks)
-            idx = np.flatnonzero(keep)
-            sub_tree = {t: {leaf: ad[leaf][idx]
-                            for leaf in ("A", "B", "mask")}
-                        for t, ad in trained.items()}
-            sub_heads = None if not trainable["head"] else {
-                k: v[idx] for k, v in trainable["head"].items()}
-            tree, up_heads = session.collect_updates(
-                cohort[idx], sub_tree, sub_heads)
-            session.aggregate_round(tree, cohort[idx],
-                                    stacked_heads=up_heads)
-            history["round"].append(rnd)
-            history["train_loss"].append(
-                float(jnp.mean(jnp.asarray(losses)[idx])))
-            history["downlink_bytes"].append(session.comm_log["downlink"][-1])
-            history["uplink_bytes"].append(session.comm_log["uplink"][-1])
-            history["stragglers"].append(int((~keep).sum()))
-            session.metrics.counter("fed.stragglers").inc(
-                int((~keep).sum()))
-            # the server closes the round when every survivor is in: at
-            # durations.max() if nobody was cut, else at the deadline —
-            # unless the force-kept fastest itself finishes after it
-            round_time = (float(durations.max()) if keep.all()
-                          else float(max(deadline, durations[keep].max())))
-            history["round_time"].append(round_time)
-            # simulated time, no clock read: always on
-            session.metrics.histogram("fed.round_time_sim").observe(
-                round_time)
-            if rec.enabled:
-                t1 = rec.now()
-                rec.complete(f"round{rnd}", "fed.rounds", t_rnd, t1,
-                             cohort=len(cohort),
-                             stragglers=int((~keep).sum()))
-                session.metrics.histogram("fed.round_s").observe(t1 - t_rnd)
-            history["health"].append(session.health_snapshot())
+                session.metrics.histogram("fed.round_s").observe(
+                    whole.seconds)
             _eval_round(history, session, eval_fn,
                         rnd % eval_every == 0 or i == num_rounds - 1)
         return history
@@ -263,7 +276,7 @@ class BufferedAsync(Scheduler):
         history: Dict[str, List] = {
             "time": [], "staleness": [], "accepted": [], "flush_events": [],
             "downlink_bytes": [], "uplink_bytes": [],
-            "eval_acc": [], "eval_loss": [], "health": []}
+            "eval_acc": [], "eval_loss": []}
         comm_seen = {k: sum(v) for k, v in session.comm_log.items()}
 
         def flush():
@@ -274,7 +287,6 @@ class BufferedAsync(Scheduler):
                 session.staleness_log[-len(buffer):])
             history["accepted"].extend(flags)
             history["flush_events"].append(len(buffer))
-            history["health"].append(session.health_snapshot())
             buffer.clear()
 
         rec = session.rec
@@ -282,20 +294,22 @@ class BufferedAsync(Scheduler):
             t_now, cid, ver = heapq.heappop(heap)
             factors, masks = split_adapters(pending[cid])
             trainable = {"factors": factors, "head": session.global_head}
-            t_tr = rec.now() if rec.enabled else 0.0
-            trained, _loss = local_train(session.base, trainable, masks,
-                                         data_fn(cid))
+            # one track per client: training bursts and arrivals line up
+            # against the server's flush spans
+            track = f"fed.client{cid}"
+            with rec.span("fed.data", track):
+                batches = data_fn(cid)
+            with rec.span("fed.train", track, version=int(ver),
+                          t_sim=float(t_now)):
+                trained, _loss = local_train(session.base, trainable, masks,
+                                             batches)
             if rec.enabled:
-                # one track per client: training bursts and arrivals
-                # line up against the server's flush spans
-                track = f"fed.client{cid}"
-                rec.complete("train", track, t_tr, rec.now(),
-                             version=int(ver), t_sim=float(t_now))
                 rec.instant("update_arrival", track, version=int(ver),
                             staleness=int(session.version - ver))
-            buffer.append(session.make_update(
-                cid, join_adapters(trained["factors"], masks), ver,
-                head=trained["head"]))
+            with rec.span("fed.collect", SERVER_TRACK, cohort=1):
+                buffer.append(session.make_update(
+                    cid, join_adapters(trained["factors"], masks), ver,
+                    head=trained["head"]))
             if len(buffer) >= self.buffer_size:
                 flush()
             history["time"].append(t_now)

@@ -51,7 +51,14 @@ from repro.fed import messages as msg_lib
 from repro.fed import strategies as strat_lib
 from repro.fed.population import sampler_from_name
 from repro.models import transformer as tf_lib
-from repro.obs import NULL_RECORDER, MetricsRegistry, percentile
+from repro.obs import NULL_RECORDER, MetricsRegistry
+
+#: ring-buffer tracks of the fed spans: whole rounds, the server's
+#: phases within a round, and the parts of a phase (a track per level,
+#: so no track ever nests spans)
+ROUNDS_TRACK = "fed.rounds"
+SERVER_TRACK = "fed.server"
+PARTS_TRACK = "fed.server.parts"
 
 
 @dataclass
@@ -191,19 +198,12 @@ class FedSession:
         # Measured wire bytes, one entry per broadcast_cohort /
         # collect_updates / make_update / adapter_for call.
         self.comm_log: Dict[str, List[int]] = {"downlink": [], "uplink": []}
-        # Observability: recorder defaults to the no-op singleton;
-        # metrics are always on. Server-side phases record on the
-        # "fed.server" track (schedulers put rounds and client training
-        # on their own tracks, so no track ever nests spans).
+        # Observability: recorder defaults to the no-op singleton (its
+        # spans still reach a running profiler capture); metrics are
+        # always on. Server phases record on SERVER_TRACK, their parts
+        # on PARTS_TRACK.
         self.rec = recorder if recorder is not None else NULL_RECORDER
         self.metrics = metrics if metrics is not None else MetricsRegistry()
-        # Per-round health snapshots (see ``health_snapshot``): the
-        # deployment-facing signal — wire bytes, stragglers, staleness
-        # — with z-score anomaly detection over the snapshot history.
-        # Observe-only; not persisted by save/restore.
-        self.health_log: List[Dict[str, float]] = []
-        self.health_z_threshold: float = 3.0
-        self._health_seen: Dict[str, float] = {}
         if population is not None and population.metrics is None:
             population.metrics = self.metrics
         # Live BufferedAsync scheduler state ({heap, pending, buffer}),
@@ -282,18 +282,19 @@ class FedSession:
         k = len(cohort)
         r_max = self.cfg.lora.r_max
         out = {}
-        for t, ad in self.global_lora.items():
-            cap = None if self.target_ranks is None \
-                else self.target_ranks.get(t)
-            m = self._cohort_masks(cohort, ad["mask"].shape, cap)
-            a = jnp.broadcast_to(ad["A"][None], (k, *ad["A"].shape)) \
-                * m[..., None, :]
-            b = jnp.broadcast_to(ad["B"][None], (k, *ad["B"].shape)) \
-                * m[..., :, None]
-            if self.strategy.scale_correction:
-                r_eff = jnp.maximum(jnp.sum(m, axis=-1), 1.0)  # (K, *stack)
-                b = b * (r_eff / float(r_max))[..., None, None]
-            out[t] = {"A": a, "B": b, "mask": m}
+        with self.rec.span("fed.redistribute", PARTS_TRACK, cohort=k):
+            for t, ad in self.global_lora.items():
+                cap = None if self.target_ranks is None \
+                    else self.target_ranks.get(t)
+                m = self._cohort_masks(cohort, ad["mask"].shape, cap)
+                a = jnp.broadcast_to(ad["A"][None], (k, *ad["A"].shape)) \
+                    * m[..., None, :]
+                b = jnp.broadcast_to(ad["B"][None], (k, *ad["B"].shape)) \
+                    * m[..., :, None]
+                if self.strategy.scale_correction:
+                    r_eff = jnp.maximum(jnp.sum(m, axis=-1), 1.0)
+                    b = b * (r_eff / float(r_max))[..., None, None]
+                out[t] = {"A": a, "B": b, "mask": m}
         return out
 
     def _client_ranks(self, cid: int) -> Dict[str, int]:
@@ -337,39 +338,44 @@ class FedSession:
         redistribution — masked directions are exactly zero), logging the
         measured downlink bytes.
         """
-        with self.rec.span("broadcast", "fed.server", cohort=len(cohort)):
+        rec, k = self.rec, len(cohort)
+        with rec.span("fed.broadcast", SERVER_TRACK, cohort=k):
             stacked = self.redistribute(cohort)
             if not self.track_comm:
                 self._log_comm("downlink", 0)
                 return stacked, self.cohort_heads(cohort)
             r_max = self.cfg.lora.r_max
             per_client, heads, total = [], [], 0
-            for i, cid in enumerate(cohort):
-                sl = {t: {"A": ad["A"][i], "B": ad["B"][i]}
-                      for t, ad in stacked.items()}
-                wire = msg_lib.Broadcast.from_bytes(
-                    self.make_broadcast(cid, sl).to_bytes())
-                total += wire.num_bytes
-                tree, head = wire.unpack(r_max)
-                per_client.append(tree)
-                heads.append(head)
+            with rec.span("fed.downlink", PARTS_TRACK, cohort=k):
+                for i, cid in enumerate(cohort):
+                    sl = {t: {"A": ad["A"][i], "B": ad["B"][i]}
+                          for t, ad in stacked.items()}
+                    wire = msg_lib.Broadcast.from_bytes(
+                        self.make_broadcast(cid, sl).to_bytes())
+                    total += wire.num_bytes
+                    tree, head = wire.unpack(r_max)
+                    per_client.append(tree)
+                    heads.append(head)
             self._log_comm("downlink", total)
-            return self._stack_clients(per_client, heads)
+            with rec.span("fed.restack", PARTS_TRACK, cohort=k):
+                return self._stack_clients(per_client, heads)
 
     def adapter_for(self, cid: int) -> Tuple[Dict, int]:
         """Async client-facing broadcast: rank-r_k truncation of the
         current global adapter (shared redistribution path — strategy
         gating and per-target caps included) + server version."""
-        stacked = self.redistribute(np.array([cid]))
-        sl = {t: {k2: v[0] for k2, v in ad.items()}
-              for t, ad in stacked.items()}
-        if self.track_comm:
-            wire = msg_lib.Broadcast.from_bytes(
-                self.make_broadcast(cid, sl).to_bytes())
+        with self.rec.span("fed.broadcast", SERVER_TRACK, cohort=1):
+            stacked = self.redistribute(np.array([cid]))
+            sl = {t: {k2: v[0] for k2, v in ad.items()}
+                  for t, ad in stacked.items()}
+            if not self.track_comm:
+                return sl, self.version
+            with self.rec.span("fed.downlink", PARTS_TRACK, cohort=1):
+                wire = msg_lib.Broadcast.from_bytes(
+                    self.make_broadcast(cid, sl).to_bytes())
+                tree, _head = wire.unpack(self.cfg.lora.r_max)
             self._log_comm("downlink", wire.num_bytes)
-            tree, _head = wire.unpack(self.cfg.lora.r_max)
             return tree, self.version
-        return sl, self.version
 
     def make_update(self, cid: int, trained_lora: Dict, start_version: int,
                     head=None, log: bool = True) -> msg_lib.ClientUpdate:
@@ -400,26 +406,30 @@ class FedSession:
         round), returning the re-stacked tree+heads ready for
         :meth:`aggregate_round`. Bit-exact: gradients cannot flow into
         masked directions, so truncation loses nothing."""
-        with self.rec.span("collect", "fed.server", cohort=len(cohort)):
+        rec, k = self.rec, len(cohort)
+        with rec.span("fed.collect", SERVER_TRACK, cohort=k):
             if not self.track_comm:
                 self._log_comm("uplink", 0)
                 return trained_tree, trained_heads
             r_max = self.cfg.lora.r_max
             per_client, heads, total = [], [], 0
-            for i, cid in enumerate(cohort):
-                sl = {t: {leaf: ad[leaf][i] for leaf in ("A", "B", "mask")}
-                      for t, ad in trained_tree.items()}
-                h = None if trained_heads is None else \
-                    {k: v[i] for k, v in trained_heads.items()}
-                upd = msg_lib.ClientUpdate.from_bytes(
-                    self.make_update(cid, sl, self.version, h,
-                                     log=False).to_bytes())
-                total += upd.num_bytes
-                tree, head = upd.unpack(r_max)
-                per_client.append(tree)
-                heads.append(head)
+            with rec.span("fed.uplink", PARTS_TRACK, cohort=k):
+                for i, cid in enumerate(cohort):
+                    sl = {t: {leaf: ad[leaf][i]
+                              for leaf in ("A", "B", "mask")}
+                          for t, ad in trained_tree.items()}
+                    h = None if trained_heads is None else \
+                        {n: v[i] for n, v in trained_heads.items()}
+                    upd = msg_lib.ClientUpdate.from_bytes(
+                        self.make_update(cid, sl, self.version, h,
+                                         log=False).to_bytes())
+                    total += upd.num_bytes
+                    tree, head = upd.unpack(r_max)
+                    per_client.append(tree)
+                    heads.append(head)
             self._log_comm("uplink", total)
-            out, heads_st = self._stack_clients(per_client, heads)
+            with rec.span("fed.restack", PARTS_TRACK, cohort=k):
+                out, heads_st = self._stack_clients(per_client, heads)
             return out, (heads_st or None) if trained_heads is not None \
                 else None
 
@@ -435,8 +445,8 @@ class FedSession:
         the per-client data weights when the stacked items are not the
         cohort itself — the hierarchical root merge passes per-edge
         weights ``n_e/Σn_e`` over pre-merged edge aggregates."""
-        with self.rec.span("aggregate", "fed.server", cohort=len(cohort),
-                           round=self.rounds_done):
+        with self.rec.span("fed.aggregate", SERVER_TRACK,
+                           cohort=len(cohort), round=self.rounds_done):
             eta = self.cohort_weights(cohort) if weights is None \
                 else jnp.asarray(weights, jnp.float32)
             if stacked_heads:
@@ -493,7 +503,7 @@ class FedSession:
             len(taus) - len(keep))
         if not keep:
             return flags
-        with self.rec.span("flush", "fed.server", merged=len(keep),
+        with self.rec.span("fed.flush", SERVER_TRACK, merged=len(keep),
                            version=self.version):
             return self._flush_merge(updates, taus, keep, flags)
 
@@ -605,77 +615,6 @@ class FedSession:
 
     def comm_totals(self) -> Dict[str, int]:
         return {k: int(sum(v)) for k, v in self.comm_log.items()}
-
-    # -- health snapshots ----------------------------------------------------
-
-    #: snapshot keys scanned for z-score anomalies against the history
-    _HEALTH_ANOMALY_KEYS = ("downlink_bytes", "uplink_bytes",
-                            "stragglers", "staleness_p99")
-
-    def health_snapshot(self) -> Dict[str, float]:
-        """One per-round (or per-flush) health row: wire bytes,
-        straggler count, merged/dropped updates and staleness
-        percentiles *since the previous snapshot*, appended to
-        ``health_log``.
-
-        With >= 3 prior snapshots, each key in
-        ``_HEALTH_ANOMALY_KEYS`` is z-scored against the history; a
-        |z| above ``health_z_threshold`` records a ``health_anomaly``
-        instant on the ``obs.slo`` track and bumps the
-        ``fed.health.anomalies`` counter. Observe-only: this is the
-        signal the ROADMAP's SLO-aware deadline tuning will consume —
-        nothing here changes scheduling. All inputs are already-counted
-        state (no clock reads), so snapshots are always on, like the
-        metrics they read."""
-        seen = self._health_seen
-
-        def delta(key: str, cur: float) -> float:
-            d = cur - seen.get(key, 0.0)
-            seen[key] = cur
-            return float(d)
-
-        snap: Dict[str, float] = {
-            "round": float(self.rounds_done),
-            "version": float(self.version),
-            "downlink_bytes": delta("downlink",
-                                    sum(self.comm_log["downlink"])),
-            "uplink_bytes": delta("uplink", sum(self.comm_log["uplink"])),
-            "stragglers": delta(
-                "stragglers",
-                self.metrics.counter("fed.stragglers").value),
-            "updates_merged": delta(
-                "merged", self.metrics.counter("fed.updates_merged").value),
-            "updates_dropped": delta(
-                "dropped",
-                self.metrics.counter("fed.updates_dropped").value),
-        }
-        new_stale = self.staleness_log[int(seen.get("stale_n", 0)):]
-        seen["stale_n"] = float(len(self.staleness_log))
-        if new_stale:
-            snap["staleness_p50"] = float(percentile(new_stale, 50))
-            snap["staleness_p99"] = float(percentile(new_stale, 99))
-        else:
-            snap["staleness_p50"] = snap["staleness_p99"] = 0.0
-        anomalies = []
-        if len(self.health_log) >= 3:
-            for k in self._HEALTH_ANOMALY_KEYS:
-                hist = np.asarray([h[k] for h in self.health_log],
-                                  np.float64)
-                sd = float(hist.std())
-                if sd <= 1e-12:
-                    continue
-                z = (snap[k] - float(hist.mean())) / sd
-                if abs(z) > self.health_z_threshold:
-                    anomalies.append(k)
-                    self.metrics.counter("fed.health.anomalies").inc()
-                    if self.rec.enabled:
-                        self.rec.instant("health_anomaly", "obs.slo",
-                                         metric=k, z=float(z),
-                                         value=snap[k],
-                                         round=self.rounds_done)
-        snap["anomalies"] = float(len(anomalies))
-        self.health_log.append(snap)
-        return snap
 
     # -- checkpoint / resume -------------------------------------------------
 

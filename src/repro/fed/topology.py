@@ -47,6 +47,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.fed import messages as msg_lib
+from repro.fed.session import PARTS_TRACK, SERVER_TRACK
 
 
 @dataclass
@@ -121,27 +122,26 @@ class HierarchicalTopology:
         per_client: List = [None] * k
         heads: List = [None] * k
         uplink_total = 0
-        with rec.span("collect", "fed.server", cohort=k,
+        with rec.span("fed.collect", SERVER_TRACK, cohort=k,
                       edges=len(groups)):
             for e, pos in enumerate(groups):
                 if len(pos) == 0:
                     continue
                 track = f"fed.edge{e}"
-                t0 = rec.now() if rec.enabled else 0.0
-                updates = []
-                for i in pos:
-                    sl, h = self._slice_client(trained_tree, trained_heads,
-                                               int(i))
-                    updates.append(session.make_update(
-                        int(cohort[i]), sl, session.version, h, log=False))
-                uplink_total += sum(u.num_bytes for u in updates)
-                agg = msg_lib.EdgeAggregate(edge_id=e, updates=updates)
-                rt = msg_lib.EdgeAggregate.from_bytes(agg.to_bytes())
-                session._log_comm(f"edge{e}_uplink", agg.num_bytes,
-                                  track=track)
-                if rec.enabled:
-                    rec.complete("edge_forward", track, t0, rec.now(),
-                                 clients=len(pos), bytes=agg.num_bytes)
+                # the edge's bytes land as a counter sample on its track
+                with rec.span("fed.edge_forward", track, clients=len(pos)):
+                    updates = []
+                    for i in pos:
+                        sl, h = self._slice_client(
+                            trained_tree, trained_heads, int(i))
+                        updates.append(session.make_update(
+                            int(cohort[i]), sl, session.version, h,
+                            log=False))
+                    uplink_total += sum(u.num_bytes for u in updates)
+                    agg = msg_lib.EdgeAggregate(edge_id=e, updates=updates)
+                    rt = msg_lib.EdgeAggregate.from_bytes(agg.to_bytes())
+                    session._log_comm(f"edge{e}_uplink", agg.num_bytes,
+                                      track=track)
                 # reassemble per-client trees in original cohort order —
                 # identical inputs to the flat collect_updates stacking
                 for i, upd in zip(pos, rt.updates):
@@ -149,11 +149,47 @@ class HierarchicalTopology:
                     per_client[int(i)] = tree
                     heads[int(i)] = head
             session._log_comm("uplink", uplink_total)
-        out, heads_st = session._stack_clients(per_client, heads)
+            with rec.span("fed.restack", PARTS_TRACK, cohort=k):
+                out, heads_st = session._stack_clients(per_client, heads)
         session.aggregate_round(
             out, cohort,
             stacked_heads=(heads_st or None)
             if trained_heads is not None else None)
+
+    @staticmethod
+    def _edge_merge(session, e: int, track: str, tree_e, heads_e, eta_e,
+                    n_e):
+        """One edge's pre-merge at cohort-local weights, shipped to the
+        root as ONE r_max update (the message that actually shrinks root
+        fan-in bytes); returns what the root unpacks."""
+        r_max = session.cfg.lora.r_max
+        full = {t: jnp.ones_like(ad["mask"][:1])
+                for t, ad in tree_e.items()}
+        out, _spec = session.engine(
+            tree_e, eta_e, session.cfg.lora.alpha,
+            **session.strategy.engine_kwargs(), new_masks=full)
+        merged = {t: {"A": ad["A"][0], "B": ad["B"][0],
+                      "mask": ad["mask"][0]}
+                  for t, ad in out.items()}
+        head_m = {}
+        if heads_e:
+            head_m = jax.tree.map(
+                lambda x: jnp.tensordot(
+                    eta_e, x.astype(jnp.float32),
+                    axes=1).astype(x.dtype), heads_e)
+        if not session.track_comm:
+            session._log_comm(f"edge{e}_uplink", 0, track=track)
+            return merged, head_m
+        upd_e = msg_lib.ClientUpdate(
+            client_id=e, start_version=session.version,
+            num_examples=int(n_e.sum()),
+            adapter=msg_lib.truncate_adapter(
+                merged, {t: r_max for t in merged}),
+            head={kk: np.asarray(v) for kk, v in head_m.items()},
+            codec=session.codec)
+        rt = msg_lib.ClientUpdate.from_bytes(upd_e.to_bytes())
+        session._log_comm(f"edge{e}_uplink", rt.num_bytes, track=track)
+        return rt.unpack(r_max)
 
     def _aggregate_engine(self, session, cohort, groups, trained_tree,
                           trained_heads) -> None:
@@ -161,7 +197,7 @@ class HierarchicalTopology:
         r_max = session.cfg.lora.r_max
         edge_trees, edge_heads, edge_sizes = [], [], []
         uplink_total = 0
-        with rec.span("collect", "fed.server", cohort=len(cohort),
+        with rec.span("fed.collect", SERVER_TRACK, cohort=len(cohort),
                       edges=len(groups)):
             for e, pos in enumerate(groups):
                 if len(pos) == 0:
@@ -188,43 +224,10 @@ class HierarchicalTopology:
                 sub = cohort[np.asarray(pos)]
                 n_e = session.client_sizes[sub].astype(np.float64)
                 eta_e = jnp.asarray(n_e / n_e.sum(), jnp.float32)
-                t0 = rec.now() if rec.enabled else 0.0
-                full = {t: jnp.ones_like(ad["mask"][:1])
-                        for t, ad in tree_e.items()}
-                out, _spec = session.engine(
-                    tree_e, eta_e, session.cfg.lora.alpha,
-                    **session.strategy.engine_kwargs(), new_masks=full)
-                merged = {t: {"A": ad["A"][0], "B": ad["B"][0],
-                              "mask": ad["mask"][0]}
-                          for t, ad in out.items()}
-                head_m = {}
-                if heads_e:
-                    head_m = jax.tree.map(
-                        lambda x: jnp.tensordot(
-                            eta_e, x.astype(jnp.float32),
-                            axes=1).astype(x.dtype), heads_e)
-                if session.track_comm:
-                    # edge → root: ONE pre-merged r_max update per edge —
-                    # the message that actually shrinks root fan-in bytes
-                    upd_e = msg_lib.ClientUpdate(
-                        client_id=e, start_version=session.version,
-                        num_examples=int(n_e.sum()),
-                        adapter=msg_lib.truncate_adapter(
-                            merged, {t: r_max for t in merged}),
-                        head={kk: np.asarray(v)
-                              for kk, v in head_m.items()},
-                        codec=session.codec)
-                    rt = msg_lib.ClientUpdate.from_bytes(upd_e.to_bytes())
-                    session._log_comm(f"edge{e}_uplink", rt.num_bytes,
-                                      track=track)
-                    tree_r, head_r = rt.unpack(r_max)
-                else:
-                    session._log_comm(f"edge{e}_uplink", 0, track=track)
-                    tree_r, head_r = merged, head_m
-                if rec.enabled:
-                    rec.complete("edge_merge", track, t0, rec.now(),
-                                 clients=len(pos),
-                                 examples=int(n_e.sum()))
+                with rec.span("fed.edge_merge", track, clients=len(pos),
+                              examples=int(n_e.sum())):
+                    tree_r, head_r = self._edge_merge(
+                        session, e, track, tree_e, heads_e, eta_e, n_e)
                 edge_trees.append(tree_r)
                 edge_heads.append(head_r)
                 edge_sizes.append(float(n_e.sum()))

@@ -40,7 +40,7 @@ from typing import Dict, Iterable, List, Optional
 from repro.obs.recorder import Event, NULL_RECORDER, Recorder
 from repro.obs.timeseries import TimeSeries, iter_observations
 
-#: the track SLO violations and health anomalies are recorded on
+#: the track SLO violations are recorded on
 SLO_TRACK = "obs.slo"
 
 

@@ -854,15 +854,12 @@ class ServeEngine:
             # reading it asynchronously — mutating in place races.
             toks = np.zeros((1, c), np.int32)
             toks[0, :nv] = prompt[lo:lo + nv]
-            t0 = rec.now() if rec.enabled else 0.0
-            with rec.annotation("serve.prefill_chunk"):
+            with rec.span("serve.prefill_chunk", self._track(req),
+                          pos0=int(lo), tokens=int(nv)):
                 logits, pools = self._prefill(
                     self.params, self.registry.slabs(), self.kv.pools,
                     self.kv.prefill_tables(row), idx,
                     jnp.asarray(toks), np.int32(lo), np.int32(nv))
-            if rec.enabled:
-                rec.complete("prefill_chunk", self._track(req), t0,
-                             rec.now(), pos0=int(lo), tokens=int(nv))
             self.kv.pools = pools
             self.prefill_calls += 1
         # Sharded prefill stacks every shard's (C, V) logits; only the
@@ -1006,30 +1003,26 @@ class ServeEngine:
             idx[i] = req["slot"]
             lens[i] = t + 1
         rec = self.rec
-        t0 = rec.now() if rec.enabled else 0.0
-        if self.kv_mode == "paged":
-            perm, inv = self._slot_order(idx, lens > 0)
-            with rec.annotation("serve.decode_step"):
+        # the argmax harvest inside blocks on the logits, so the span is
+        # a true step latency (host + device)
+        with rec.span("serve.decode_step", self._engine_track,
+                      batch=len(active)) as step:
+            if self.kv_mode == "paged":
+                perm, inv = self._slot_order(idx, lens > 0)
                 logits, self.kv.pools = self._step(
                     self.params, self.registry.slabs(), self.kv.pools,
                     jnp.asarray(self.kv.tables[perm]),
                     jnp.asarray(idx[perm]), jnp.asarray(tokens[perm]),
                     jnp.asarray(pos[perm]), jnp.asarray(lens[perm]))
                 nxt = np.asarray(jnp.argmax(logits, axis=-1), np.int32)[inv]
-        else:
-            with rec.annotation("serve.decode_step"):
+            else:
                 logits, self.cache = self._step(
                     self.params, self.registry.slabs(), self.cache,
                     jnp.asarray(idx), jnp.asarray(tokens), jnp.asarray(pos))
                 nxt = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
         if rec.enabled:
-            # the argmax harvest above blocked on the logits, so this
-            # span is a true step latency (host + device)
-            t1 = rec.now()
-            rec.complete("decode_step", self._engine_track, t0, t1,
-                         batch=len(active))
             self.metrics.histogram(
-                f"{self.name}.decode_step_s").observe(t1 - t0)
+                f"{self.name}.decode_step_s").observe(step.seconds)
         self.steps += 1
         for i, req in active:
             req["t"] += 1
@@ -1071,8 +1064,8 @@ class ServeEngine:
             idx[i] = req["slot"]
         perm, inv = self._slot_order(idx, nv > 0)
         rec = self.rec
-        t0 = rec.now() if rec.enabled else 0.0
-        with rec.annotation("serve.verify_step"):
+        with rec.span("serve.verify_step", self._engine_track,
+                      batch=len(active)) as step:
             logits, self.kv.pools = self._verify(
                 self.params, self.registry.slabs(), self.kv.pools,
                 jnp.asarray(self.kv.tables[perm]), jnp.asarray(idx[perm]),
@@ -1080,11 +1073,8 @@ class ServeEngine:
                 jnp.asarray(nv[perm]))
             greedy = np.asarray(jnp.argmax(logits, axis=-1), np.int32)[inv]
         if rec.enabled:
-            t1 = rec.now()
-            rec.complete("verify_step", self._engine_track, t0, t1,
-                         batch=len(active))
             self.metrics.histogram(
-                f"{self.name}.decode_step_s").observe(t1 - t0)
+                f"{self.name}.decode_step_s").observe(step.seconds)
         self.steps += 1
         self.spec_dispatches += 1
         for i, req in active:

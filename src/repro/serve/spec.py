@@ -145,25 +145,25 @@ class SelfDrafter:
             lens[i] = req["t"] + 1
         alive = (lens > 0).astype(np.int32)
         rec = engine.rec
-        t0 = rec.now() if rec.enabled else 0.0
-        for step in range(k_use):
-            with rec.annotation("serve.draft_step"):
-                logits, engine.kv.pools = self._step(
-                    engine.params, engine.registry.slabs(),
-                    engine.kv.pools, jnp.asarray(engine.kv.tables),
-                    jnp.asarray(idx), jnp.asarray(cur), jnp.asarray(pos),
-                    jnp.asarray(lens))
-                nxt = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
-            for j, (i, _) in enumerate(active):
-                props[j, step] = nxt[i]
-            cur = nxt[:, None].copy()
-            pos = pos + alive
-            lens = lens + alive
-        if rec.enabled:
-            # one span per draft burst; the verify span starts after
-            # this returns, so the engine track never nests
-            rec.complete("draft", engine._engine_track, t0, rec.now(),
-                         k=int(k_use), batch=len(active))
+        # one span per draft burst on the engine track (the verify span
+        # starts after this returns, so that track never nests); its
+        # steps go on a track of their own
+        with rec.span("serve.draft", engine._engine_track, k=int(k_use),
+                      batch=len(active)):
+            for step in range(k_use):
+                with rec.span("serve.draft_step",
+                              f"{engine.name}/draft"):
+                    logits, engine.kv.pools = self._step(
+                        engine.params, engine.registry.slabs(),
+                        engine.kv.pools, jnp.asarray(engine.kv.tables),
+                        jnp.asarray(idx), jnp.asarray(cur),
+                        jnp.asarray(pos), jnp.asarray(lens))
+                    nxt = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
+                for j, (i, _) in enumerate(active):
+                    props[j, step] = nxt[i]
+                cur = nxt[:, None].copy()
+                pos = pos + alive
+                lens = lens + alive
         return props
 
 

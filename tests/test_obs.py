@@ -15,12 +15,14 @@ Layers under test:
 * A ``FedSession`` recorded through broadcast → collect → aggregate →
   async flush — server spans in order, measured wire-byte counters
   matching ``comm_log``, and staleness accounting on the flush path.
+* Spans in a running ``jax.profiler`` capture — from ``NULL_RECORDER``
+  and a ``Recorder`` alike, nothing touched outside one — and a
+  ``SyncRound`` round's span tree, read back from a CPU capture.
 * The *watching* layer (PR 8) — streaming time-series bucketing
   (count/total conservation property-tested across bucket sizes,
   bounded memory via horizon eviction), SLO attainment/burn-rate math
   with its edge cases, per-class TTFT attainment on the engine,
-  per-round health snapshots with forced z-score anomalies on the
-  session, cross-process clock rebasing (synthetic AND a real
+  cross-process clock rebasing (synthetic AND a real
   subprocess child), ring-truncation surfacing in both exporters, and
   the HTML/terminal ops report.
 """
@@ -98,8 +100,7 @@ def test_null_recorder_is_a_true_noop():
     NULL_RECORDER.counter_sample("c", "t", 1)
     with NULL_RECORDER.span("d", "t"):
         pass
-    with NULL_RECORDER.annotation("e"):
-        pass
+    assert not hasattr(NULL_RECORDER, "annotation")
     assert len(NULL_RECORDER) == 0 and NULL_RECORDER.events() == []
     assert NULL_RECORDER.dropped == 0
 
@@ -241,8 +242,9 @@ def test_recorded_run_exports_valid_chrome_trace(recorded):
     counts = validate_chrome_trace(doc)
     assert counts["X"] > 0 and counts["i"] > 0
     names = {e[1] for e in rec.events()}
-    for want in ("submit", "admit", "prefill_chunk", "first_token",
-                 "decode_step", "finish", "defer", "preempt", "replay"):
+    for want in ("submit", "admit", "serve.prefill_chunk", "first_token",
+                 "serve.decode_step", "finish", "defer", "preempt",
+                 "replay"):
         assert want in names, f"missing {want!r} in the recorded trace"
     # one track per request plus the engine track
     tracks = {e[2] for e in rec.events()}
@@ -406,7 +408,8 @@ def test_fed_server_spans_in_order(fed_recorded):
     server = [e for e in rec.events()
               if e[2] == "fed.server" and e[0] == "X"]
     names = [e[1] for e in server]
-    assert names == ["broadcast", "collect", "aggregate", "flush"]
+    assert names == ["fed.broadcast", "fed.collect", "fed.aggregate",
+                     "fed.flush"]
     # sequential host code: already-sorted, non-overlapping
     for (_, _, _, a0, ad, _), (_, _, _, b0, _, _) in zip(server,
                                                          server[1:]):
@@ -435,7 +438,7 @@ def test_fed_flush_staleness_accounting(fed_recorded):
     assert metrics.counter("fed.updates_dropped").value == 1
     stale_h = metrics.histogram("fed.staleness")
     assert stale_h.count == 2 and stale_h.vmax == 5
-    flush = [e for e in rec.events() if e[1] == "flush"]
+    flush = [e for e in rec.events() if e[1] == "fed.flush"]
     assert len(flush) == 1 and flush[0][5]["merged"] == 1
 
 
@@ -450,6 +453,143 @@ def test_fed_default_session_records_nothing():
     # metrics stay on regardless: wire bytes still counted
     assert sess.metrics.counter("fed.downlink_bytes").value == \
         sum(sess.comm_log["downlink"]) > 0
+
+
+# ---------------------------------------------------------------------------
+# spans in a running profiler capture
+# ---------------------------------------------------------------------------
+
+def _capture_host_spans(tmp_path, fn):
+    """Run ``fn`` under a CPU ``jax.profiler`` capture; the (name, start,
+    end) of every event on the capture's python host lines."""
+    import glob
+    from jax.profiler import ProfileData
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    paths = sorted(glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                             recursive=True))
+    pd = ProfileData.from_file(paths[-1])
+    return [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+            for plane in pd.planes if plane.name == "/host:CPU"
+            for line in plane.lines if line.name.startswith("python")
+            for ev in line.events]
+
+
+def _disabled_recorder():
+    rec = Recorder()
+    rec.enabled = False
+    return rec
+
+
+@pytest.mark.parametrize("make", [lambda: NULL_RECORDER, Recorder],
+                         ids=["null", "recorder"])
+def test_span_reaches_a_running_capture(tmp_path, make):
+    rec = make()
+
+    def work():
+        with rec.span("fed.broadcast", "fed.server", cohort=2):
+            pass
+    spans = _capture_host_spans(tmp_path, work)
+    assert [n for n, _, _ in spans if n.startswith("fed.")] == \
+        ["fed.broadcast"]
+    assert [e[1] for e in rec.events()] == \
+        (["fed.broadcast"] if rec.enabled else [])
+
+
+@pytest.mark.parametrize("make", [lambda: NULL_RECORDER,
+                                  _disabled_recorder],
+                         ids=["null", "disabled"])
+def test_span_outside_a_capture_touches_nothing(monkeypatch, make):
+    """No capture running and recording off: one flag check, the shared
+    null context back, no annotation opened, nothing in the ring."""
+    from repro.obs import recorder as recorder_mod
+
+    def refuse(name):
+        raise AssertionError(f"annotation {name!r} with no capture")
+    monkeypatch.setattr(recorder_mod, "TraceAnnotation", refuse)
+    rec = make()
+    ctx = rec.span("fed.broadcast", "fed.server", cohort=2)
+    assert ctx is recorder_mod._NULL_CTX
+    with ctx:
+        pass
+    assert len(rec) == 0 and rec.events() == [] and rec.appended == 0
+
+
+def test_recorder_span_outside_a_capture_fills_only_the_ring(
+        monkeypatch):
+    from repro.obs import recorder as recorder_mod
+
+    def refuse(name):
+        raise AssertionError(f"annotation {name!r} with no capture")
+    monkeypatch.setattr(recorder_mod, "TraceAnnotation", refuse)
+    rec = Recorder()
+    with rec.span("fed.collect", "fed.server", cohort=3) as sp:
+        pass
+    (kind, name, track, t0, dur, args), = rec.events()
+    assert (kind, name, track, args) == ("X", "fed.collect", "fed.server",
+                                         {"cohort": 3})
+    assert dur == sp.seconds >= 0.0
+
+
+#: the span tree of one SyncRound round (fed/schedulers.py)
+ROUND_TREE = ("fed.round", [
+    ("fed.broadcast", [("fed.redistribute", []), ("fed.downlink", []),
+                       ("fed.restack", [])]),
+    ("fed.data", []),
+    ("fed.train", []),
+    ("fed.collect", [("fed.uplink", []), ("fed.restack", [])]),
+    ("fed.aggregate", []),
+    ("fed.close", [])])
+
+
+def _nest(spans):
+    """(name, start, end) spans nested by containment, in start order:
+    a list of (name, [children])."""
+    roots, stack = [], []
+    for name, s, e in sorted(spans, key=lambda x: (x[1], -x[2])):
+        while stack and not (stack[-1][0] <= s and e <= stack[-1][1]):
+            stack.pop()
+        node = (name, [])
+        (stack[-1][2] if stack else roots).append(node)
+        stack.append((s, e, node[1]))
+    return roots
+
+
+def test_sync_round_span_tree_in_a_capture(tmp_path):
+    """One reduced-width round under a CPU capture: the round's phases,
+    in order and properly nested, on the capture's host line and in the
+    ring buffer (one track per level, so the Chrome export validates)."""
+    from repro.fed import SyncRound
+    cfg = get_reduced("roberta-large")
+    scfg = ServerConfig(num_clients=4, clients_per_round=2,
+                        strategy="hlora", rank_policy="random",
+                        r_min=2, r_max=8, seed=0)
+    base = model_lib.init_params(jax.random.PRNGKey(5), cfg)
+    rec = Recorder()
+    sess = FedSession(cfg, scfg, base, recorder=rec)
+
+    def train(frozen, trainable, masks, batches):
+        return trainable, np.zeros(len(batches), np.float32)
+
+    def data_fn(cohort, rnd):
+        return np.asarray(cohort)
+    SyncRound().run(sess, train, data_fn, 1)     # compiles the merge
+    rec.clear()
+    spans = _capture_host_spans(
+        tmp_path, lambda: SyncRound().run(sess, train, data_fn, 1))
+    assert _nest([s for s in spans if s[0].startswith("fed.")]) == \
+        [ROUND_TREE]
+    xs = [e for e in rec.events() if e[0] == "X"]
+    assert _nest([(e[1], e[3], e[3] + e[4]) for e in xs]) == [ROUND_TREE]
+    tracks = {e[1]: e[2] for e in xs}
+    assert (tracks["fed.round"], tracks["fed.train"],
+            tracks["fed.uplink"]) == ("fed.rounds", "fed.server",
+                                      "fed.server.parts")
+    assert [e[5] for e in xs if e[1] == "fed.round"] == [{"round": 1}]
+    validate_chrome_trace(chrome_trace(rec.events()))
 
 
 # ---------------------------------------------------------------------------
@@ -675,56 +815,6 @@ def test_engine_slo_classes_inert_without_recorder(serve_setup):
     engine.run()
     assert engine.slo_attainment() == {}
     assert engine.metrics.counter("serve.slo.fast.total").value == 0
-
-
-# ---------------------------------------------------------------------------
-# fed health snapshots: per-round deltas + z-score anomalies
-# ---------------------------------------------------------------------------
-
-def test_fed_health_snapshots_and_forced_anomaly():
-    """Steady wire traffic with slight jitter, then a 100x spike: the
-    spike must z-score as an anomaly (instant on obs.slo + counter),
-    and snapshots must report deltas, not running totals."""
-    cfg = get_reduced("roberta-large")
-    scfg = ServerConfig(num_clients=4, clients_per_round=2, seed=0)
-    base = model_lib.init_params(jax.random.PRNGKey(3), cfg)
-    rec = Recorder()
-    metrics = MetricsRegistry()
-    sess = FedSession(cfg, scfg, base, recorder=rec, metrics=metrics)
-    for step, down in enumerate((1000.0, 1010.0, 990.0, 1005.0)):
-        sess.comm_log["downlink"].append(down)
-        sess.comm_log["uplink"].append(down / 2)
-        sess.staleness_log.append(step % 2)
-        snap = sess.health_snapshot()
-        assert snap["downlink_bytes"] == pytest.approx(down)
-        assert snap["anomalies"] == 0.0
-    assert len(sess.health_log) == 4
-    assert sess.health_log[-1]["staleness_p99"] == 1.0
-    # the spike: two orders of magnitude over the steady mean
-    sess.comm_log["downlink"].append(100000.0)
-    sess.comm_log["uplink"].append(500.0)
-    snap = sess.health_snapshot()
-    assert snap["anomalies"] >= 1.0
-    assert metrics.counter("fed.health.anomalies").value >= 1
-    anom = [e for e in rec.events()
-            if e[1] == "health_anomaly" and e[2] == SLO_TRACK]
-    assert anom and anom[0][5]["metric"] == "downlink_bytes"
-    assert abs(anom[0][5]["z"]) > sess.health_z_threshold
-
-
-def test_fed_health_snapshot_keys_are_deltas():
-    """Back-to-back snapshots with no traffic in between report zeros —
-    the snapshot is a rate window, not a cumulative read."""
-    cfg = get_reduced("roberta-large")
-    scfg = ServerConfig(num_clients=2, clients_per_round=2, seed=0)
-    base = model_lib.init_params(jax.random.PRNGKey(4), cfg)
-    sess = FedSession(cfg, scfg, base)
-    sess.broadcast_cohort(np.array([0, 1]))
-    first = sess.health_snapshot()
-    assert first["downlink_bytes"] > 0
-    second = sess.health_snapshot()
-    assert second["downlink_bytes"] == 0.0
-    assert second["staleness_p50"] == 0.0   # no new staleness entries
 
 
 # ---------------------------------------------------------------------------
