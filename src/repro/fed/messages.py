@@ -33,6 +33,7 @@ import struct
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -116,22 +117,63 @@ def truncate_adapter(tree, ranks: Dict[str, int]) -> AdapterPayload:
     return out
 
 
+class CohortStack:
+    """Host arrays of a cohort's decoded payloads, zero-padded back to
+    r_max and stacked over the clients, written in place one client at a
+    time; the inverse of :func:`truncate_adapter` for a whole cohort.
+
+    The arrays are allocated from the first payload put (zeros, in the
+    dtype a device copy of it takes), so each client's rank tail is
+    exactly zero and its mask row is ``arange(r_max) < r``. ``tree`` and
+    ``heads`` go to the device in one transfer.
+    """
+
+    def __init__(self, k: int, r_max: int):
+        self.k, self.r_max = int(k), int(r_max)
+        self.tree: Dict[str, Dict[str, np.ndarray]] = {}
+        self.heads: Dict[str, np.ndarray] = {}
+        self._empty = True
+
+    def _allocate(self, adapter: AdapterPayload, head: HeadPayload) -> None:
+        k, r = self.k, self.r_max
+
+        def zeros(shape, like):
+            return np.zeros(shape, jax.dtypes.canonicalize_dtype(like.dtype))
+        for t, ad in adapter.items():
+            a, b = _np(ad["A"]), _np(ad["B"])
+            self.tree[t] = {
+                "A": zeros((k, *a.shape[:-1], r), a),
+                "B": zeros((k, *b.shape[:-2], r, b.shape[-1]), b),
+                "mask": np.zeros((k, *a.shape[:-2], r), np.float32)}
+        for n, v in head.items():
+            v = _np(v)
+            self.heads[n] = zeros((k, *v.shape), v)
+        self._empty = False
+
+    def put(self, i: int, adapter: AdapterPayload,
+            head: HeadPayload) -> None:
+        """Write client ``i``'s decoded (rank-truncated) payload."""
+        if self._empty:
+            self._allocate(adapter, head)
+        for t, ad in adapter.items():
+            a, b = _np(ad["A"]), _np(ad["B"])
+            r = a.shape[-1]
+            st = self.tree[t]
+            st["A"][i, ..., :r] = a
+            st["B"][i, ..., :r, :] = b
+            st["mask"][i, ..., :r] = 1.0
+        for n, v in head.items():
+            self.heads[n][i] = v
+
+
 def pad_adapter(payload: AdapterPayload, r_max: int):
-    """Inverse of :func:`truncate_adapter`: zero-pad factors back to r_max
-    and rebuild the rank mask from the payload's truncated rank."""
-    out = {}
-    for t, ad in payload.items():
-        a, b = _np(ad["A"]), _np(ad["B"])
-        r = a.shape[-1]
-        pad_a = [(0, 0)] * (a.ndim - 1) + [(0, r_max - r)]
-        pad_b = [(0, 0)] * (b.ndim - 2) + [(0, r_max - r), (0, 0)]
-        mask = np.broadcast_to(
-            (np.arange(r_max) < r).astype(np.float32),
-            (*a.shape[:-2], r_max))
-        out[t] = {"A": jnp.asarray(np.pad(a, pad_a)),
-                  "B": jnp.asarray(np.pad(b, pad_b)),
-                  "mask": jnp.asarray(mask)}
-    return out
+    """Inverse of :func:`truncate_adapter` for one client: factors
+    zero-padded back to r_max and the rank mask rebuilt from the
+    payload's truncated rank, on the device."""
+    stack = CohortStack(1, r_max)
+    stack.put(0, payload, {})
+    return {t: {leaf: jnp.asarray(v[0]) for leaf, v in ad.items()}
+            for t, ad in stack.tree.items()}
 
 
 def _flatten_payload(adapter: AdapterPayload, head: HeadPayload

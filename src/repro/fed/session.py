@@ -22,7 +22,11 @@ out-of-band. ``FedSession`` unifies all of it:
   serialized ``Broadcast``/``ClientUpdate`` messages — rank-truncated and
   dtype-aware — and log measured uplink/downlink bytes. Round-trip is
   bit-exact (masked directions are exactly zero), so the measured path IS
-  the compute path.
+  the compute path. The cohort path serializes each client from one host
+  copy of the stack a round and sends the decoded stack back in one
+  transfer (``fed.wire_transfers`` counts them): per-client device slices
+  and read-backs cost a dispatch or a device sync each, which left the
+  chip idle for most of a round.
 * **Schedulers** (``fed/schedulers.py``): ``SyncRound`` / ``SemiSync`` /
   ``BufferedAsync`` drive the session; the session itself never blocks on
   a cohort barrier — ``aggregate_round`` and ``flush_async`` are the only
@@ -115,6 +119,15 @@ def assign_ranks(scfg: ServerConfig, client_sizes, capacities=None,
         # starts at r_max; adapt_ranks() tightens it after each round
         return rank_lib.uniform_ranks(n, scfg.r_max)
     raise ValueError(scfg.rank_policy)
+
+
+def client_slice(tree, heads, i: int):
+    """Client ``i``'s slice of a cohort stack and of its heads (None
+    stays None)."""
+    sl = {t: {leaf: v[i] for leaf, v in ad.items()}
+          for t, ad in tree.items()}
+    h = None if heads is None else {n: v[i] for n, v in heads.items()}
+    return sl, h
 
 
 class FedSession:
@@ -309,26 +322,31 @@ class FedSession:
 
     # -- wire-level broadcast / collect -------------------------------------
 
-    def make_broadcast(self, cid: int, stacked_slice) -> msg_lib.Broadcast:
+    def to_host(self, tree):
+        """One device -> host transfer of a whole pytree, counted in
+        ``fed.wire_transfers``."""
+        self.metrics.counter("fed.wire_transfers").inc()
+        return jax.device_get(tree)
+
+    def to_device(self, tree):
+        """One host -> device transfer of a whole pytree, counted in
+        ``fed.wire_transfers``."""
+        self.metrics.counter("fed.wire_transfers").inc()
+        return jax.device_put(tree)
+
+    def make_broadcast(self, cid: int, stacked_slice,
+                       head=None) -> msg_lib.Broadcast:
         """One client's ``Broadcast`` message from its slice of the
-        redistributed stack (already masked + scale-corrected)."""
+        redistributed stack (already masked + scale-corrected); ``head``
+        is a host copy of the global head (read from it when None)."""
         ranks = self._client_ranks(cid)
         payload = msg_lib.truncate_adapter(stacked_slice, ranks)
+        head = self.global_head if head is None else head
         return msg_lib.Broadcast(version=self.version, client_id=int(cid),
                                  adapter=payload,
                                  head={k: np.asarray(v) for k, v
-                                       in self.global_head.items()},
+                                       in head.items()},
                                  codec=self.codec)
-
-    @staticmethod
-    def _stack_clients(per_client, heads):
-        """Re-stack per-client unpacked trees/heads into cohort arrays."""
-        out = {t: {leaf: jnp.stack([c[t][leaf] for c in per_client])
-                   for leaf in ("A", "B", "mask")}
-               for t in per_client[0]}
-        heads_st = jax.tree.map(lambda *xs: jnp.stack(xs), *heads) \
-            if heads and heads[0] else {}
-        return out, heads_st
 
     def broadcast_cohort(self, cohort: np.ndarray):
         """Redistribute to a cohort through the wire format.
@@ -336,7 +354,10 @@ class FedSession:
         Returns ``(stacked_tree, stacked_heads)`` reconstructed from the
         serialized ``Broadcast`` messages (bit-identical to the in-memory
         redistribution — masked directions are exactly zero), logging the
-        measured downlink bytes.
+        measured downlink bytes. The cohort crosses between device and
+        host twice a round whatever its size: one host copy of the stack
+        and head, each client's message built from views of it, and one
+        device copy of the decoded stack.
         """
         rec, k = self.rec, len(cohort)
         with rec.span("fed.broadcast", SERVER_TRACK, cohort=k):
@@ -344,21 +365,21 @@ class FedSession:
             if not self.track_comm:
                 self._log_comm("downlink", 0)
                 return stacked, self.cohort_heads(cohort)
-            r_max = self.cfg.lora.r_max
-            per_client, heads, total = [], [], 0
+            stack = msg_lib.CohortStack(k, self.cfg.lora.r_max)
+            total = 0
             with rec.span("fed.downlink", PARTS_TRACK, cohort=k):
+                host, head = self.to_host((
+                    {t: {"A": ad["A"], "B": ad["B"]}
+                     for t, ad in stacked.items()}, self.global_head))
                 for i, cid in enumerate(cohort):
-                    sl = {t: {"A": ad["A"][i], "B": ad["B"][i]}
-                          for t, ad in stacked.items()}
+                    sl, _ = client_slice(host, None, i)
                     wire = msg_lib.Broadcast.from_bytes(
-                        self.make_broadcast(cid, sl).to_bytes())
+                        self.make_broadcast(cid, sl, head).to_bytes())
                     total += wire.num_bytes
-                    tree, head = wire.unpack(r_max)
-                    per_client.append(tree)
-                    heads.append(head)
+                    stack.put(i, wire.adapter, wire.head)
             self._log_comm("downlink", total)
             with rec.span("fed.restack", PARTS_TRACK, cohort=k):
-                return self._stack_clients(per_client, heads)
+                return self.to_device((stack.tree, stack.heads))
 
     def adapter_for(self, cid: int) -> Tuple[Dict, int]:
         """Async client-facing broadcast: rank-r_k truncation of the
@@ -405,31 +426,29 @@ class FedSession:
         messages (measured uplink, one consolidated comm_log row per
         round), returning the re-stacked tree+heads ready for
         :meth:`aggregate_round`. Bit-exact: gradients cannot flow into
-        masked directions, so truncation loses nothing."""
+        masked directions, so truncation loses nothing. Like the
+        broadcast, one host copy of the trained stack (each client's rank
+        read from its mask there) and one device copy of the decoded one."""
         rec, k = self.rec, len(cohort)
         with rec.span("fed.collect", SERVER_TRACK, cohort=k):
             if not self.track_comm:
                 self._log_comm("uplink", 0)
                 return trained_tree, trained_heads
-            r_max = self.cfg.lora.r_max
-            per_client, heads, total = [], [], 0
+            stack = msg_lib.CohortStack(k, self.cfg.lora.r_max)
+            total = 0
             with rec.span("fed.uplink", PARTS_TRACK, cohort=k):
+                host, host_heads = self.to_host((trained_tree,
+                                                 trained_heads))
                 for i, cid in enumerate(cohort):
-                    sl = {t: {leaf: ad[leaf][i]
-                              for leaf in ("A", "B", "mask")}
-                          for t, ad in trained_tree.items()}
-                    h = None if trained_heads is None else \
-                        {n: v[i] for n, v in trained_heads.items()}
+                    sl, h = client_slice(host, host_heads, i)
                     upd = msg_lib.ClientUpdate.from_bytes(
                         self.make_update(cid, sl, self.version, h,
                                          log=False).to_bytes())
                     total += upd.num_bytes
-                    tree, head = upd.unpack(r_max)
-                    per_client.append(tree)
-                    heads.append(head)
+                    stack.put(i, upd.adapter, upd.head)
             self._log_comm("uplink", total)
             with rec.span("fed.restack", PARTS_TRACK, cohort=k):
-                out, heads_st = self._stack_clients(per_client, heads)
+                out, heads_st = self.to_device((stack.tree, stack.heads))
             return out, (heads_st or None) if trained_heads is not None \
                 else None
 

@@ -47,7 +47,17 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.fed import messages as msg_lib
-from repro.fed.session import PARTS_TRACK, SERVER_TRACK
+from repro.fed.session import PARTS_TRACK, SERVER_TRACK, client_slice
+
+
+def _stack_trees(per_client, heads):
+    """Stack per-client device trees/heads into cohort arrays."""
+    out = {t: {leaf: jnp.stack([c[t][leaf] for c in per_client])
+               for leaf in ("A", "B", "mask")}
+           for t in per_client[0]}
+    heads_st = jax.tree.map(lambda *xs: jnp.stack(xs), *heads) \
+        if heads and heads[0] else {}
+    return out, heads_st
 
 
 @dataclass
@@ -98,14 +108,6 @@ class HierarchicalTopology:
             self._aggregate_engine(session, cohort, groups, trained_tree,
                                    trained_heads)
 
-    @staticmethod
-    def _slice_client(trained_tree, trained_heads, i: int):
-        sl = {t: {leaf: ad[leaf][i] for leaf in ("A", "B", "mask")}
-              for t, ad in trained_tree.items()}
-        h = None if trained_heads is None else \
-            {k: v[i] for k, v in trained_heads.items()}
-        return sl, h
-
     def _aggregate_stack(self, session, cohort, groups, trained_tree,
                          trained_heads) -> None:
         rec = session.rec
@@ -117,13 +119,13 @@ class HierarchicalTopology:
             session.aggregate_round(trained_tree, cohort,
                                     stacked_heads=trained_heads)
             return
-        r_max = session.cfg.lora.r_max
         k = len(cohort)
-        per_client: List = [None] * k
-        heads: List = [None] * k
+        stack = msg_lib.CohortStack(k, session.cfg.lora.r_max)
         uplink_total = 0
         with rec.span("fed.collect", SERVER_TRACK, cohort=k,
                       edges=len(groups)):
+            host, host_heads = session.to_host((trained_tree,
+                                                trained_heads))
             for e, pos in enumerate(groups):
                 if len(pos) == 0:
                     continue
@@ -132,8 +134,7 @@ class HierarchicalTopology:
                 with rec.span("fed.edge_forward", track, clients=len(pos)):
                     updates = []
                     for i in pos:
-                        sl, h = self._slice_client(
-                            trained_tree, trained_heads, int(i))
+                        sl, h = client_slice(host, host_heads, int(i))
                         updates.append(session.make_update(
                             int(cohort[i]), sl, session.version, h,
                             log=False))
@@ -142,15 +143,14 @@ class HierarchicalTopology:
                     rt = msg_lib.EdgeAggregate.from_bytes(agg.to_bytes())
                     session._log_comm(f"edge{e}_uplink", agg.num_bytes,
                                       track=track)
-                # reassemble per-client trees in original cohort order —
-                # identical inputs to the flat collect_updates stacking
+                # each client's decoded update lands at its cohort
+                # position — the same stack the flat collect_updates builds
                 for i, upd in zip(pos, rt.updates):
-                    tree, head = upd.unpack(r_max)
-                    per_client[int(i)] = tree
-                    heads[int(i)] = head
+                    stack.put(int(i), upd.adapter, upd.head)
             session._log_comm("uplink", uplink_total)
             with rec.span("fed.restack", PARTS_TRACK, cohort=k):
-                out, heads_st = session._stack_clients(per_client, heads)
+                out, heads_st = session.to_device((stack.tree,
+                                                   stack.heads))
         session.aggregate_round(
             out, cohort,
             stacked_heads=(heads_st or None)
@@ -207,8 +207,8 @@ class HierarchicalTopology:
                 # flat path collects (consolidated into the uplink row)
                 per, hds = [], []
                 for i in pos:
-                    sl, h = self._slice_client(trained_tree, trained_heads,
-                                               int(i))
+                    sl, h = client_slice(trained_tree, trained_heads,
+                                         int(i))
                     if session.track_comm:
                         upd = msg_lib.ClientUpdate.from_bytes(
                             session.make_update(int(cohort[i]), sl,
@@ -220,7 +220,7 @@ class HierarchicalTopology:
                         tree, head = sl, (h or {})
                     per.append(tree)
                     hds.append(head)
-                tree_e, heads_e = session._stack_clients(per, hds)
+                tree_e, heads_e = _stack_trees(per, hds)
                 sub = cohort[np.asarray(pos)]
                 n_e = session.client_sizes[sub].astype(np.float64)
                 eta_e = jnp.asarray(n_e / n_e.sum(), jnp.float32)
@@ -233,7 +233,7 @@ class HierarchicalTopology:
                 edge_sizes.append(float(n_e.sum()))
             session._log_comm("uplink", uplink_total)
         w = np.asarray(edge_sizes, np.float64)
-        out, heads_st = session._stack_clients(edge_trees, edge_heads)
+        out, heads_st = _stack_trees(edge_trees, edge_heads)
         session.aggregate_round(
             out, cohort,
             stacked_heads=(heads_st or None)

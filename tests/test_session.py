@@ -258,6 +258,160 @@ def test_downlink_bytes_rank_truncated(cfg, base):
     assert (sizes[0] - head_b) < 0.3 * (sizes[1] - head_b)
 
 
+def _bits_equal(got, want, what):
+    got, want = np.asarray(got), np.asarray(want)
+    assert (got.dtype, got.shape) == (want.dtype, want.shape), what
+    assert got.tobytes() == want.tobytes(), what
+
+
+def _unpack(msg, r_max):
+    """A message's client-side view at r_max, padded here with ``np.pad``
+    and the mask rebuilt from the payload's rank, apart from the code
+    under test."""
+    tree = {}
+    for t, ad in msg.adapter.items():
+        a, b = np.asarray(ad["A"]), np.asarray(ad["B"])
+        r = a.shape[-1]
+        tree[t] = {
+            "A": np.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, r_max - r)]),
+            "B": np.pad(b, [(0, 0)] * (b.ndim - 2)
+                        + [(0, r_max - r), (0, 0)]),
+            "mask": np.broadcast_to(
+                (np.arange(r_max) < r).astype(np.float32),
+                (*a.shape[:-2], r_max))}
+    return tree, {n: np.asarray(v) for n, v in msg.head.items()}
+
+
+def _reference_restack(msgs, r_max):
+    """The per-client wire path, one message at a time: to_bytes ->
+    from_bytes -> unpack, then a stack over the clients; and the summed
+    measured bytes."""
+    trees, heads, nbytes = [], [], 0
+    for m in msgs:
+        back = type(m).from_bytes(m.to_bytes())
+        nbytes += back.num_bytes
+        tree, head = _unpack(back, r_max)
+        trees.append(tree)
+        heads.append(head)
+    out = {t: {leaf: np.stack([c[t][leaf] for c in trees])
+               for leaf in ("A", "B", "mask")} for t in trees[0]}
+    hs = {n: np.stack([h[n] for h in heads]) for n in heads[0]}
+    return out, hs, nbytes
+
+
+def _mask_ranks(tree, i, r_max):
+    return {t: int(np.asarray(ad["mask"][i]).reshape(-1, r_max)[0].sum())
+            for t, ad in tree.items()}
+
+
+def _client_payload(tree, i):
+    return {t: {"A": np.asarray(ad["A"][i]), "B": np.asarray(ad["B"][i])}
+            for t, ad in tree.items()}
+
+
+@pytest.mark.parametrize("caps", [None, {"q": 3, "v": 5}],
+                         ids=["uncapped", "capped"])
+@pytest.mark.parametrize("codec", ["none", "bf16", "int8", "topk"])
+def test_cohort_wire_matches_per_client_reference(cfg, base, codec, caps):
+    """A round's batched broadcast and collect equal, bit for bit, a
+    reference that round-trips each client's message alone, and log the
+    sum of those messages' measured bytes."""
+    from repro.fed import compress as compress_lib
+    scfg = ServerConfig(num_clients=6, clients_per_round=4,
+                        strategy="hlora", rank_policy="random", r_min=2,
+                        r_max=8, seed=0, codec=codec)
+    sess = FedSession(cfg, scfg, base, client_sizes=np.arange(1, 7) * 10)
+    sess.target_ranks = caps
+    key = jax.random.PRNGKey(11)
+    sess.global_lora = {
+        t: {**ad, "B": jax.random.normal(jax.random.fold_in(key, j),
+                                         ad["B"].shape)}
+        for j, (t, ad) in enumerate(sess.global_lora.items())}
+    r_max, wire_codec = cfg.lora.r_max, compress_lib.from_name(codec)
+    cohort = np.array([4, 0, 5, 2])
+
+    red = sess.redistribute(cohort)
+    head = {n: np.asarray(v) for n, v in sess.global_head.items()}
+    want, want_heads, want_bytes = _reference_restack(
+        [msg_lib.Broadcast(version=sess.version, client_id=int(cid),
+                           adapter=msg_lib.truncate_adapter(
+                               _client_payload(red, i),
+                               _mask_ranks(red, i, r_max)),
+                           head=head, codec=wire_codec)
+         for i, cid in enumerate(cohort)], r_max)
+    tree, heads = sess.broadcast_cohort(cohort)
+    for t in want:
+        for leaf in ("A", "B", "mask"):
+            _bits_equal(tree[t][leaf], want[t][leaf], f"down {t}.{leaf}")
+    assert set(heads) == set(want_heads) and heads
+    for n in want_heads:
+        _bits_equal(heads[n], want_heads[n], f"down head {n}")
+    assert sess.comm_log["downlink"][-1] == want_bytes
+
+    # a trained stack: steps only in each client's unmasked directions
+    trained = {}
+    for j, (t, ad) in enumerate(tree.items()):
+        ka, kb = jax.random.split(jax.random.fold_in(key, 100 + j))
+        m = ad["mask"]
+        trained[t] = {
+            "A": ad["A"] + 0.01 * jax.random.normal(ka, ad["A"].shape)
+            * m[..., None, :],
+            "B": ad["B"] + 0.01 * jax.random.normal(kb, ad["B"].shape)
+            * m[..., :, None],
+            "mask": m}
+    trained_heads = {n: v + 0.5 for n, v in heads.items()}
+    want, want_heads, want_bytes = _reference_restack(
+        [msg_lib.ClientUpdate(
+            client_id=int(cid), start_version=sess.version,
+            num_examples=int(sess.client_sizes[cid]),
+            adapter=msg_lib.truncate_adapter(
+                _client_payload(trained, i),
+                _mask_ranks(trained, i, r_max)),
+            head={n: np.asarray(v[i]) for n, v in trained_heads.items()},
+            codec=wire_codec)
+         for i, cid in enumerate(cohort)], r_max)
+    got, got_heads = sess.collect_updates(cohort, trained, trained_heads)
+    for t in want:
+        for leaf in ("A", "B", "mask"):
+            _bits_equal(got[t][leaf], want[t][leaf], f"up {t}.{leaf}")
+    for n in want_heads:
+        _bits_equal(got_heads[n], want_heads[n], f"up head {n}")
+    assert sess.comm_log["uplink"][-1] == want_bytes
+    assert sess.comm_log["downlink"][-1] > 0 and want_bytes > 0
+
+
+def test_wire_transfers_do_not_grow_with_the_cohort(cfg, base, monkeypatch):
+    """A round's broadcast + collect move the cohort between device and
+    host in a fixed number of transfer calls, at 2 clients as at 6, and
+    ``fed.wire_transfers`` counts exactly those calls."""
+    calls = []
+    real_get, real_put = jax.device_get, jax.device_put
+
+    def get(x):
+        calls.append("get")
+        return real_get(x)
+
+    def put(x, *a, **kw):
+        calls.append("put")
+        return real_put(x, *a, **kw)
+    monkeypatch.setattr(jax, "device_get", get)
+    monkeypatch.setattr(jax, "device_put", put)
+    per_round = {}
+    for k in (2, 6):
+        scfg = ServerConfig(num_clients=8, clients_per_round=k,
+                            strategy="hlora", rank_policy="random", seed=0)
+        sess = FedSession(cfg, scfg, base)
+        cohort = sess.sample_cohort()
+        counter = sess.metrics.counter("fed.wire_transfers")
+        calls.clear()
+        stacked, heads = sess.broadcast_cohort(cohort)
+        tree, up_heads = sess.collect_updates(cohort, stacked, heads)
+        assert len(cohort) == k and len(tree["q"]["A"]) == k
+        assert counter.value == len(calls)
+        per_round[k] = counter.value
+    assert per_round[2] == per_round[6] == 4
+
+
 # ---------------------------------------------------------------------------
 # Satellite: async redistribution gated on strategy (seed bug: hlora scale
 # applied under naive), via the one shared redistribution path
