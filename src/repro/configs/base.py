@@ -30,7 +30,8 @@ class LoRAConfig:
 @dataclass(frozen=True)
 class ModelConfig:
     name: str
-    arch_type: str  # dense | moe | ssm | hybrid | audio | vlm | encoder
+    # dense | moe | ssm | hybrid | hybrid_moe | audio | vlm | encoder
+    arch_type: str
     num_layers: int
     d_model: int
     num_heads: int
@@ -44,15 +45,30 @@ class ModelConfig:
     moe_d_ff: int = 0          # per-expert ffn width (defaults to d_ff)
     moe_shared: bool = False   # llama4-style always-on shared expert
     moe_group_size: int = 1024  # tokens per dispatch group (perf knob)
-    moe_capacity_factor: float = 1.25
+    moe_capacity_factor: float = 1.25   # moe_ffn's; routed_moe is dropless
+    # expert parallelism: this device holds experts
+    # [moe_expert_offset, moe_expert_offset + moe_experts_held) of the
+    # num_experts the router scores (0 held = all of them)
+    moe_experts_held: int = 0
+    moe_expert_offset: int = 0
     # SSM (mamba2 / hymba)
     ssm_state: int = 0
     ssm_expand: int = 2
     ssm_head_dim: int = 64
     ssm_conv_width: int = 4
+    ssm_chunk: int = 128       # SSD chunk length (perf knob, same result)
+    # hybrid_moe: the mixer of each layer in order ("mamba" | "attention")
+    layer_types: Tuple[str, ...] = ()
     # attention
     sliding_window: Optional[int] = None   # None = full attention
     rope_theta: float = 10000.0
+    attention_multiplier: float = 0.0   # score scale; 0 -> 1/sqrt(head_dim)
+    # muP-style multipliers (hybrid_moe): embedding output, each residual
+    # branch, and the divisor of the logits
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    norm_eps: float = 1e-6
     # ffn
     activation: str = "silu"   # silu | geglu | gelu
     use_bias: bool = False
@@ -78,19 +94,28 @@ class ModelConfig:
         return self.d_inner // self.ssm_head_dim if self.ssm_state else 0
 
     @property
+    def num_held_experts(self) -> int:
+        return self.moe_experts_held or self.num_experts
+
+    def layers_of(self, kind: str) -> int:
+        """How many layers of a hybrid_moe pattern have mixer ``kind``."""
+        return sum(t == kind for t in self.layer_types)
+
+    @property
     def is_attention_free(self) -> bool:
         return self.arch_type == "ssm"
 
     @property
     def supports_decode(self) -> bool:
-        return self.arch_type not in ("encoder",)
+        return self.arch_type not in ("encoder", "hybrid_moe")
 
     def supports_long_decode(self) -> bool:
         """long_500k eligibility: sub-quadratic decode memory.
 
         SSM/hybrid natively; dense/moe/vlm only when a sliding window is
         configured (we enable one for the long_500k dry-run variant);
-        whisper and roberta are skipped (see DESIGN.md).
+        whisper (a 30 s audio domain) and roberta (no decode step) are
+        skipped.
         """
         if self.arch_type in ("ssm", "hybrid"):
             return True
@@ -106,6 +131,14 @@ class ModelConfig:
         d, L = self.d_model, self.num_layers
         hd = self.resolved_head_dim
         emb = self.vocab_size * d
+        if self.arch_type == "hybrid_moe":
+            attn = d * self.num_heads * hd * 2 + 2 * d * self.num_kv_heads * hd
+            di, n = self.d_inner, self.ssm_state
+            mamba = d * (2 * di + 2 * n + self.ssm_heads) + di * d
+            moe = (d * self.num_experts + self.num_held_experts * 3 * d
+                   * self.moe_d_ff + 3 * d * self.d_ff)
+            return (emb + self.layers_of("attention") * attn
+                    + self.layers_of("mamba") * mamba + L * moe)
         out_head = 0 if self.tie_embeddings else self.vocab_size * d
         if self.num_classes:
             out_head = d * self.num_classes
@@ -169,6 +202,7 @@ ARCH_IDS = (
     "gemma_2b",
     "command_r_plus_104b",
     "roberta_large",  # the paper's own model
+    "granite_4_0_h_small",
 )
 
 _ALIASES = {

@@ -21,7 +21,9 @@ CONFIG = ModelConfig(
     moe_d_ff=8192,
     moe_shared=True,
     activation="silu",
-    lora=LoRAConfig(targets=("q", "k", "v", "o")),  # not on routed experts (DESIGN.md)
+    # LoRA on attention only: routed experts are frozen and shared by every
+    # client, so their per-expert weights never enter the adapter
+    lora=LoRAConfig(targets=("q", "k", "v", "o")),
     source="hf:meta-llama/Llama-4-Scout-17B-16E",
 )
 

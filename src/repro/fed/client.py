@@ -46,31 +46,41 @@ def split_head(base_params):
     return frozen, head
 
 
+#: routing statistics a model's loss reports (hybrid_moe)
+STAT_KEYS = ("moe_load", "moe_dropped")
+
+
 def make_local_train(cfg: ModelConfig, opt, remat: bool = False,
                      q_chunk: int = 1024):
     """Returns local_train(frozen_base, trainable, masks, data) ->
     (trainable', mean_loss) with trainable = {"factors", "head"}.
-    ``data`` leaves are (steps, batch, ...)."""
+    ``data`` leaves are (steps, batch, ...). A model whose loss reports
+    routing statistics (``STAT_KEYS``: hybrid_moe) adds them, summed over
+    the steps, as a third output."""
 
     def loss(trainable, masks, frozen, batch):
         params = {**frozen, **trainable["head"],
                   "lora": join_adapters(trainable["factors"], masks)}
-        l, _ = model_lib.loss_fn(params, batch, cfg, remat=remat,
-                                 q_chunk=q_chunk)
-        return l
+        l, metrics = model_lib.loss_fn(params, batch, cfg, remat=remat,
+                                       q_chunk=q_chunk)
+        return l, {k: metrics[k] for k in STAT_KEYS if k in metrics}
 
     def local_train(frozen, trainable, masks, data):
         opt_state = opt.init(trainable)
 
         def step_fn(carry, batch):
             tr, st = carry
-            l, g = jax.value_and_grad(loss)(tr, masks, frozen, batch)
+            (l, stats), g = jax.value_and_grad(loss, has_aux=True)(
+                tr, masks, frozen, batch)
             upd, st = opt.update(g, st, tr)
             tr = apply_updates(tr, upd)
-            return (tr, st), l
+            return (tr, st), (l, stats)
 
-        (trainable, _), losses = lax.scan(
+        (trainable, _), (losses, stats) = lax.scan(
             step_fn, (trainable, opt_state), data)
+        if stats:
+            return trainable, jnp.mean(losses), jax.tree.map(
+                lambda s: jnp.sum(s, axis=0), stats)
         return trainable, jnp.mean(losses)
 
     return local_train

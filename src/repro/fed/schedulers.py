@@ -91,7 +91,9 @@ class SyncRound(Scheduler):
     def run(self, session, train, data_fn, num_rounds: int,
             eval_fn=None, eval_every: int = 1) -> Dict[str, List]:
         """``train(frozen, trainable, masks, data) -> (trainable, losses)``
-        is the vmapped cohort trainer; ``data_fn(cohort, rnd)`` returns
+        is the vmapped cohort trainer (a third output, a model's routing
+        statistics, goes to ``session.record_routing``);
+        ``data_fn(cohort, rnd)`` returns
         the cohort's stacked batches. Resuming a restored session
         continues the round index from ``session.rounds_done``."""
         history: Dict[str, List] = {
@@ -109,8 +111,8 @@ class SyncRound(Scheduler):
                     batches = data_fn(cohort, rnd)
                 with rec.span("fed.train", SERVER_TRACK, round=rnd,
                               cohort=len(cohort)):
-                    trainable, losses = train(session.base, trainable,
-                                              masks, batches)
+                    out = train(session.base, trainable, masks, batches)
+                trainable, losses = out[:2]
                 trained = join_adapters(trainable["factors"], masks)
                 if self.topology is not None:
                     self.topology.aggregate(session, cohort, trained,
@@ -123,6 +125,8 @@ class SyncRound(Scheduler):
                 with rec.span("fed.close", SERVER_TRACK, round=rnd):
                     history["round"].append(rnd)
                     history["train_loss"].append(float(jnp.mean(losses)))
+                    if len(out) > 2:
+                        session.record_routing(out[2])
                     _wire_rows(history, session)
             if rec.enabled:
                 session.metrics.histogram("fed.round_s").observe(
@@ -175,8 +179,8 @@ class SemiSync(Scheduler):
                     batches = data_fn(cohort, rnd)
                 with rec.span("fed.train", SERVER_TRACK, round=rnd,
                               cohort=len(cohort)):
-                    trainable, losses = train(session.base, trainable,
-                                              masks, batches)
+                    out = train(session.base, trainable, masks, batches)
+                trainable, losses = out[:2]
                 trained = join_adapters(trainable["factors"], masks)
                 idx = np.flatnonzero(keep)
                 sub_tree = {t: {leaf: ad[leaf][idx]
@@ -192,6 +196,8 @@ class SemiSync(Scheduler):
                     history["round"].append(rnd)
                     history["train_loss"].append(
                         float(jnp.mean(jnp.asarray(losses)[idx])))
+                    if len(out) > 2:
+                        session.record_routing(out[2])
                     _wire_rows(history, session)
                     history["stragglers"].append(cut)
                     session.metrics.counter("fed.stragglers").inc(cut)
@@ -301,8 +307,10 @@ class BufferedAsync(Scheduler):
                 batches = data_fn(cid)
             with rec.span("fed.train", track, version=int(ver),
                           t_sim=float(t_now)):
-                trained, _loss = local_train(session.base, trainable, masks,
-                                             batches)
+                out = local_train(session.base, trainable, masks, batches)
+            trained = out[0]
+            if len(out) > 2:
+                session.record_routing(out[2])
             if rec.enabled:
                 rec.instant("update_arrival", track, version=int(ver),
                             staleness=int(session.version - ver))

@@ -627,6 +627,24 @@ class FedSession:
                 t: agg_engine.rank_for_energy(s, e, lo, hi)
                 for t, s in spectra.items()}
 
+    def record_routing(self, stats) -> None:
+        """A round's routing statistics, as a trainer of a model with
+        routed experts returns them (``moe_load`` (..., L, held) and
+        ``moe_dropped`` (..., L), per client or for one), into the
+        registry: counters ``fed.moe_routed`` (token-expert pairs sent to
+        this device's experts) and ``fed.moe_dropped`` (of those, pairs not
+        computed), and the gauge
+        ``fed.moe_load_max_over_mean`` (the most-loaded held expert's
+        pairs over the mean, in the layer where that ratio is largest)."""
+        load = np.asarray(stats["moe_load"], np.float64)
+        load = load.reshape(-1, *load.shape[-2:]).sum(0)
+        self.metrics.counter("fed.moe_routed").inc(int(load.sum()))
+        self.metrics.counter("fed.moe_dropped").inc(
+            int(np.asarray(stats["moe_dropped"]).sum()))
+        mean = np.maximum(load.mean(-1), 1e-9)
+        self.metrics.gauge("fed.moe_load_max_over_mean").set(
+            float((load.max(-1) / mean).max()))
+
     # -- accessors -----------------------------------------------------------
 
     def global_params(self):

@@ -234,7 +234,7 @@ def run_centralized(
         picks = rng.integers(0, len(tokens), size=(chunk, sim.local_batch))
         data = {"tokens": jnp.asarray(tokens[picks]),
                 "labels": jnp.asarray(labels[picks])}
-        trainable, loss = local(frozen, trainable, masks, data)
+        trainable, loss = local(frozen, trainable, masks, data)[:2]
         m = eval_fn(frozen, trainable)
         history["round"].append(rnd)
         history["train_loss"].append(float(loss))

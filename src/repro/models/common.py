@@ -86,13 +86,14 @@ def attention(
     kv_positions: Optional[jax.Array] = None,  # (B, Skv) absolute, for caches
     kv_valid: Optional[jax.Array] = None,      # (B, Skv) bool
     q_chunk: int = 1024,
+    scale: Optional[float] = None,   # score scale; None -> 1/sqrt(Dh)
 ) -> jax.Array:
     b, sq, h, dh = q.shape
     hkv = k.shape[2]
     k = _repeat_kv(k, h // hkv)
     v = _repeat_kv(v, h // hkv)
     skv = k.shape[1]
-    scale = 1.0 / math.sqrt(dh)
+    scale = 1.0 / math.sqrt(dh) if scale is None else scale
     if kv_positions is None:
         kv_pos = jnp.broadcast_to(jnp.arange(skv)[None, :], (b, skv))
     else:

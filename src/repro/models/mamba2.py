@@ -129,7 +129,9 @@ def ssd_chunked(
     inputs = (
         jnp.moveaxis(xc, 1, 0), jnp.moveaxis(dtc, 1, 0),
         jnp.moveaxis(bc, 1, 0), jnp.moveaxis(cc, 1, 0))
-    final_state, ys = lax.scan(chunk_body, state0, inputs)
+    # each chunk's (b, c, c, h) decay and score blocks are recomputed in
+    # the backward pass rather than kept for every chunk at once
+    final_state, ys = lax.scan(jax.checkpoint(chunk_body), state0, inputs)
     y = jnp.moveaxis(ys, 0, 1).reshape(b, s, h, p)
     return y.astype(x.dtype), final_state
 
@@ -165,12 +167,21 @@ def _split_proj(zxbcdt: jax.Array, cfg: ModelConfig):
     return z, xbc, dt
 
 
+def gated_norm(y: jax.Array, z: jax.Array, w: jax.Array,
+               eps: float) -> jax.Array:
+    """RMSNorm of ``y * silu(z)`` over all inner channels, in f32."""
+    f32 = jnp.float32
+    return rms_norm(y.astype(f32) * jax.nn.silu(z.astype(f32)), w, eps)
+
+
 def mamba_mixer(
     x: jax.Array, p: Dict[str, jax.Array], cfg: ModelConfig,
     adapters: Optional[Dict[str, Adapter]] = None,
-    chunk: int = SSD_CHUNK,
+    chunk: Optional[int] = None,
 ) -> jax.Array:
-    """Training/prefill path. x: (B, S, d) -> (B, S, d)."""
+    """Training/prefill path. x: (B, S, d) -> (B, S, d). The SSD, its
+    ``D`` skip and the gated norm run in f32 (the ``ssm.ssd`` scope holds
+    the SSD); ``chunk`` defaults to ``cfg.ssm_chunk``."""
     from repro.models import shard_hints
     x = shard_hints.constrain_tokens(x, x.shape[0])
     ad = adapters or {}
@@ -187,12 +198,14 @@ def mamba_mixer(
     dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])
     a = -jnp.exp(p["A_log"])
     bsz, s, _ = x.shape
-    xh = xs.reshape(bsz, s, h, pdim)
-    y, _ = ssd_chunked(xh, dt, a, bmat, cmat, chunk=chunk)
-    y = y + p["D"].astype(y.dtype)[None, None, :, None] * xh
-    y = y.reshape(bsz, s, di)
-    y = rms_norm(y * jax.nn.silu(z), p["ssm_norm"])
-    return apply_lora(y, p["out_proj"], ad.get("ssm_out"), alpha)
+    xh = xs.reshape(bsz, s, h, pdim).astype(jnp.float32)
+    with jax.named_scope("ssm.ssd"):
+        y, _ = ssd_chunked(xh, dt, a, bmat, cmat,
+                           chunk=chunk or cfg.ssm_chunk)
+    y = y + p["D"].astype(jnp.float32)[None, None, :, None] * xh
+    y = gated_norm(y.reshape(bsz, s, di), z, p["ssm_norm"], cfg.norm_eps)
+    return apply_lora(y.astype(x.dtype), p["out_proj"], ad.get("ssm_out"),
+                      alpha)
 
 
 def mamba_mixer_step(
@@ -219,9 +232,9 @@ def mamba_mixer_step(
     xh = xs.reshape(-1, h, pdim)
     y, new_state = ssd_step(cache["state"], xh, dt, a, bvec, cvec)
     y = y + p["D"].astype(y.dtype)[None, :, None] * xh
-    y = y.reshape(-1, di)
-    y = rms_norm(y * jax.nn.silu(z), p["ssm_norm"])
-    out = apply_lora(y, p["out_proj"], ad.get("ssm_out"), alpha)
+    y = gated_norm(y.reshape(-1, di), z, p["ssm_norm"], cfg.norm_eps)
+    out = apply_lora(y.astype(x.dtype), p["out_proj"], ad.get("ssm_out"),
+                     alpha)
     return out[:, None, :], {"conv": new_conv, "state": new_state}
 
 

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.configs.base import ModelConfig
+from repro.models import hybrid_moe as hybrid_moe_lib
 from repro.models import hymba as hymba_lib
 from repro.models import mamba2 as ssm_lib
 from repro.models import transformer as tf_lib
@@ -87,6 +88,8 @@ def init_params(key, cfg: ModelConfig, dtype=jnp.float32):
         return _mamba_init(key, cfg, dtype)
     if cfg.arch_type == "hybrid":
         return hymba_lib.init_params(key, cfg, dtype)
+    if cfg.arch_type == "hybrid_moe":
+        return hybrid_moe_lib.init_params(key, cfg, dtype)
     if cfg.arch_type == "audio":
         return whisper_lib.init_params(key, cfg, dtype)
     return tf_lib.init_params(key, cfg, dtype)  # dense / moe / vlm / encoder
@@ -100,6 +103,9 @@ def forward(params, batch: Dict[str, jax.Array], cfg: ModelConfig, *,
     if cfg.arch_type == "hybrid":
         return hymba_lib.forward(params, tokens, cfg, remat=remat,
                                  q_chunk=q_chunk)
+    if cfg.arch_type == "hybrid_moe":
+        return hybrid_moe_lib.forward(params, tokens, cfg, remat=remat,
+                                      q_chunk=q_chunk)
     if cfg.arch_type == "audio":
         return whisper_lib.forward(params, tokens, cfg,
                                    frames=batch.get("frames"), remat=remat,
@@ -112,6 +118,9 @@ def forward(params, batch: Dict[str, jax.Array], cfg: ModelConfig, *,
 def loss_fn(params, batch, cfg: ModelConfig, *, remat: bool = True,
             q_chunk: int = 1024) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     logits, aux = forward(params, batch, cfg, remat=remat, q_chunk=q_chunk)
+    stats = {}
+    if isinstance(aux, dict):  # hybrid_moe: routing statistics, no aux loss
+        stats, aux = aux, jnp.zeros((), jnp.float32)
     labels = batch["labels"]
     if cfg.num_classes:  # sequence classification (roberta / paper tasks)
         logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
@@ -134,7 +143,7 @@ def loss_fn(params, batch, cfg: ModelConfig, *, remat: bool = True,
     mask = (labels >= 0).astype(jnp.float32)
     nll = jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
     total = nll + MOE_AUX_WEIGHT * aux
-    return total, {"loss": total, "nll": nll, "aux": aux}
+    return total, {"loss": total, "nll": nll, "aux": aux, **stats}
 
 
 def init_cache(cfg: ModelConfig, batch: int, max_seq: int, dtype=jnp.bfloat16):
@@ -146,6 +155,8 @@ def init_cache(cfg: ModelConfig, batch: int, max_seq: int, dtype=jnp.bfloat16):
         return whisper_lib.init_cache(cfg, batch, max_seq, dtype)
     if cfg.arch_type == "encoder":
         raise ValueError("encoder-only model has no decode path")
+    if cfg.arch_type == "hybrid_moe":
+        raise ValueError("hybrid_moe has no decode path (training only)")
     return tf_lib.init_cache(cfg, batch, max_seq, dtype)
 
 

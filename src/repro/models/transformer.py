@@ -69,10 +69,29 @@ def lora_specs(cfg: ModelConfig) -> Dict[str, Tuple[int, int]]:
     return specs
 
 
+ATTN_TARGETS = ("q", "k", "v", "o")
+SSM_TARGETS = ("ssm_in", "ssm_out")
+
+
+def lora_depths(cfg: ModelConfig) -> Dict[str, int]:
+    """{target: how many layers carry it}. Every layer carries every
+    target, except in a hybrid_moe pattern, where the attention targets
+    live on its attention layers and the SSM ones on its Mamba layers."""
+    out = {}
+    for t in cfg.lora.targets:
+        if cfg.arch_type == "hybrid_moe" and t in ATTN_TARGETS:
+            out[t] = cfg.layers_of("attention")
+        elif cfg.arch_type == "hybrid_moe" and t in SSM_TARGETS:
+            out[t] = cfg.layers_of("mamba")
+        else:
+            out[t] = cfg.num_layers
+    return out
+
+
 def init_lora(key, cfg: ModelConfig, rank: Optional[int] = None,
               dtype=jnp.float32) -> Dict[str, lora_lib.Adapter]:
     specs = lora_specs(cfg)
-    stack = {t: (cfg.num_layers,) for t in specs}
+    stack = {t: (n,) for t, n in lora_depths(cfg).items()}
     return lora_lib.tree_init(key, specs, cfg.lora.r_max, rank, stack, dtype)
 
 
