@@ -16,9 +16,10 @@ rather than by the worst-case ``max_seq``. The scheduler defers
 admission while the pool is dry, extends a row's page list as its decode
 crosses page boundaries, and preempts the youngest rows (their requests
 re-queue and replay — greedy decode is deterministic) when an extension
-cannot be satisfied. Decode attention reads pages through the table
-(Pallas ``kernels/paged_attn.py`` on TPU, a gather + masked softmax
-elsewhere).
+cannot be satisfied. Decode attention reads pages through the table in
+the Pallas kernel (``kernels/paged_attn.py``, TPU); the plain path reads
+each layer's pool once in place under a per-row validity mask built from
+the tables once per step.
 
 Prefill is **chunked**: a second jitted step pushes ``prefill_chunk``
 prompt tokens at a time through full attention at absolute offset
@@ -205,12 +206,53 @@ def _layer_decode_dense(x, lp, slab, lc, idx, pos, cfg: ModelConfig,
     return _layer_out(x, o, lp, slab, idx, cfg, use_pallas), lc
 
 
+def pool_kv_valid(tables, lens, pool_pages: int, page_size: int):
+    """(B, pool_pages * page_size) bool: which pool slots row ``b``
+    attends. Row ``b`` holds pool page ``p`` at table index
+    ``page_pos[b, p]`` (-1 if not at all), so slot ``s`` of that page is
+    position ``page_pos * page_size + s``, valid below ``lens[b]``. A
+    live page is never owned twice; the trash page can sit at several
+    table indices of a row, but all of them at positions ``>= lens``, so
+    whichever one the scatter keeps is masked."""
+    bsz, p = tables.shape
+    page_pos = jnp.full((bsz, pool_pages), -1, jnp.int32).at[
+        jnp.arange(bsz)[:, None], tables].max(
+        jnp.broadcast_to(jnp.arange(p, dtype=jnp.int32), (bsz, p)))
+    kv_pos = page_pos[:, :, None] * page_size + jnp.arange(page_size)
+    valid = (page_pos[:, :, None] >= 0) & (kv_pos < lens[:, None, None])
+    return valid.reshape(bsz, pool_pages * page_size)
+
+
+def pool_decode_attention(q, k_pool, v_pool, kv_valid):
+    """One decode token per row against a whole layer pool, in place.
+    q: (B, H, Dh); k_pool/v_pool: (N, ps, Hkv, Dh); kv_valid: (B, N*ps)
+    from ``pool_kv_valid`` -> (B, H, Dh) in the pool's dtype; rows with
+    no valid slot emit zeros. Scores, softmax and the output accumulate
+    in f32 at ``attention``'s precision, over exact f32 up-casts of the
+    stored K and V, which XLA fuses into the products: no f32 copy of
+    the pool is made, and the pool broadcasts over the rows."""
+    b, h, dh = q.shape
+    n, ps, hkv, _ = k_pool.shape
+    f32 = jnp.float32
+    kp = k_pool.reshape(n * ps, hkv, dh).astype(f32)
+    vp = v_pool.reshape(n * ps, hkv, dh).astype(f32)
+    qg = q.reshape(b, hkv, h // hkv, dh).astype(f32)   # head h: KV h // G
+    logits = jnp.einsum("bkgd,skd->bkgs", qg, kp) * (1.0 / math.sqrt(dh))
+    logits = jnp.where(kv_valid[:, None, None, :], logits, -1e30)
+    probs = jax.nn.softmax(logits, axis=-1)
+    out = jnp.einsum("bkgs,skd->bkgd", probs, vp)
+    out = out * jnp.any(kv_valid, axis=-1)[:, None, None, None]
+    return out.reshape(b, h, dh).astype(v_pool.dtype)
+
+
 def _layer_decode_paged(x, lp, slab, lc, idx, pos, lens, page, slot,
-                        tables, cfg: ModelConfig, use_pallas: bool,
-                        page_size: int):
+                        tables, kv_valid, cfg: ModelConfig,
+                        use_pallas: bool, page_size: int):
     """One token through one layer against the paged pool.
     page/slot: (B,) precomputed write targets (trash for inactive rows);
-    tables: (B, P) page tables; lens: (B,) valid tokens incl. this one."""
+    tables: (B, P) page tables; lens: (B,) valid tokens incl. this one;
+    kv_valid: the step's ``pool_kv_valid`` for plain attention, which
+    reads the pool in place; None under the Pallas kernel."""
     bsz = x.shape[0]
     hd = cfg.resolved_head_dim
     _, q, k, v = _layer_qkv(x, lp, slab, idx, pos[:, None], cfg, use_pallas)
@@ -221,17 +263,7 @@ def _layer_decode_paged(x, lp, slab, lc, idx, pos, lens, page, slot,
         o = ops.paged_attention(q[:, 0], lck, lcv, tables, lens,
                                 page_size=page_size)[:, None]
     else:
-        p = tables.shape[1]
-        kk = lck[tables].reshape(bsz, p * page_size, cfg.num_kv_heads, hd)
-        vv = lcv[tables].reshape(bsz, p * page_size, cfg.num_kv_heads, hd)
-        # Positions are implicit in the page-table contract: slot s of
-        # table entry j is position j*ps + s. Valid = written for *this*
-        # row: stale slots and trash-mapped entries sit at >= lens.
-        kv_pos = jnp.broadcast_to(jnp.arange(p * page_size)[None, :],
-                                  (bsz, p * page_size))
-        o = attention(q, kk, vv, causal=False, window=None,
-                      kv_positions=kv_pos,
-                      kv_valid=kv_pos < lens[:, None])
+        o = pool_decode_attention(q[:, 0], lck, lcv, kv_valid)[:, None]
     o = o.reshape(bsz, 1, cfg.num_heads * hd)
     return _layer_out(x, o, lp, slab, idx, cfg, use_pallas), {"k": lck,
                                                               "v": lcv}
@@ -409,6 +441,12 @@ class ServeEngine:
             self._step = jax.jit(step)
             self._prefill = jax.jit(prefill)
             self._verify = jax.jit(verify)
+            # KV slots decode attention reads per layer: plain attention
+            # reads each shard's sub-pool (its trash page included) in
+            # place, the Pallas kernel every row's table.
+            self.decode_kv_slots = self.kv.num_shards * self.page_size * (
+                self.kv.rows_per_shard * pages_per_row if self.use_pallas
+                else self.kv.pages_per_shard + 1)
         else:
             self.cache = init_kv_cache(cfg.num_layers, self.max_batch,
                                        self.max_seq, cfg.num_kv_heads,
@@ -451,6 +489,7 @@ class ServeEngine:
     accepted_tokens = _counter_view("spec.accepted")
     rollback_pages = _counter_view("spec.rollback_pages")
     bgmv_groups = _gauge_view("bgmv_groups")
+    decode_kv_slots = _gauge_view("decode_kv_slots")
 
     # -- request tracks ------------------------------------------------------
 
@@ -612,6 +651,15 @@ class ServeEngine:
         x = norm(x, params["final_norm"])
         return self._logits(params, x[:, 0, :]), new_cache
 
+    def _decode_kv_valid(self, tables, lens):
+        """Built once per decode step, outside the layer scan: the
+        (shard-local) pool's validity mask for plain attention; None
+        under the Pallas kernel, which reads through the tables."""
+        if self.use_pallas:
+            return None
+        return pool_kv_valid(tables, lens, self.kv.pages_per_shard + 1,
+                             self.page_size)
+
     def _paged_step_impl(self, params, slabs, pools, tables, idx, tokens,
                          pos, lens):
         """tokens: (B,1), pos: (B,), lens: (B,) valid tokens incl. this
@@ -622,12 +670,13 @@ class ServeEngine:
         page = jnp.take_along_axis(tables, (pos // ps)[:, None], axis=1)[:, 0]
         page = jnp.where(lens > 0, page, self.kv.trash)  # inactive -> trash
         slot = pos % ps
+        kv_valid = self._decode_kv_valid(tables, lens)
 
         def scan_body(carry, xs):
             lp, slab_l, lc = xs
             y, new_lc = _layer_decode_paged(
                 carry, lp, slab_l, lc, idx, pos, lens, page, slot, tables,
-                self.cfg, self.use_pallas, ps)
+                kv_valid, self.cfg, self.use_pallas, ps)
             return y, new_lc
 
         x, new_pools = lax.scan(scan_body, x,

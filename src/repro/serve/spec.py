@@ -98,12 +98,13 @@ class SelfDrafter:
             layers_d = jax.tree.map(lambda v: v[:d], params["layers"])
             slabs_d = jax.tree.map(lambda v: v[:d], slabs)
             pools_d = {kk: vv[:d] for kk, vv in pools.items()}
+            kv_valid = engine._decode_kv_valid(tables, lens)
 
             def body(carry, xs):
                 lp, slab_l, lc = xs
                 y, new_lc = engine_mod._layer_decode_paged(
                     carry, lp, slab_l, lc, idx, pos, lens, page, slot,
-                    tables, engine.cfg, engine.use_pallas, ps)
+                    tables, kv_valid, engine.cfg, engine.use_pallas, ps)
                 return y, new_lc
 
             x, new_d = lax.scan(body, x, (layers_d, slabs_d, pools_d))

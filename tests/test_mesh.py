@@ -309,6 +309,8 @@ def test_sharded_serve_preemption_exact(serve_setup):
                          mesh=mesh)
     assert engine.kv.num_shards == 4
     assert engine.kv.pages_per_shard == 5
+    # decode reads each shard's sub-pool (5 pages + its trash) in place
+    assert engine.decode_kv_slots == 4 * (5 + 1) * 4
     uids = [engine.submit(prompts[i], f"client{i % 4}",
                           max_new_tokens=STEPS) for i in range(8)]
     outs = engine.run()

@@ -276,3 +276,55 @@ def test_paged_attn_matches_contiguous_attention():
                      kv_valid=kv_pos < lens[:, None])[:, 0]
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-4, atol=2e-4)
+
+
+# ---------------------------------------------------------------------------
+# plain decode attention over the pool in place vs the gather oracle
+# ---------------------------------------------------------------------------
+
+# Rows of 4-slot pages from a 12-page pool (page 12 is the trash page):
+# (pages the row holds in table order, valid tokens). Unlisted table
+# entries are trash; every slot of every page, the trash page included,
+# holds random KV, so a slot the mask lets through shows.
+POOL_ROWS = {
+    # mid-page; one token past a boundary; a lone first token
+    "boundary_and_mid_page": [([3, 7], 5), ([0, 5, 9], 9), ([2], 1),
+                              ([11, 4, 8, 6], 14)],
+    "exact_page_multiple": [([3, 7], 8), ([0, 5, 9, 1], 16), ([2], 4),
+                            ([10], 4)],
+    # inactive rows hold nothing: all-trash tables, length 0
+    "inactive_rows": [([], 0), ([0, 5], 7), ([], 0), ([], 0)],
+    # page 5 went from row 0 back to the pool and on to row 1, which has
+    # written only its first slot: slots 1-3 keep row 0's stale KV
+    "reused_stale_page": [([3], 2), ([6, 5], 5), ([1, 2, 4], 12),
+                          ([], 0)],
+}
+
+
+@pytest.mark.parametrize("h,hkv", [(4, 4), (8, 2)], ids=["mha", "gqa"])
+@pytest.mark.parametrize("case", sorted(POOL_ROWS))
+def test_pool_decode_attention_matches_gather_oracle(case, h, hkv):
+    """The plain decode path reads the whole layer pool under a per-row
+    validity mask built from the tables; it must equal the gather
+    oracle, f32 on the CPU, row for row (empty rows emit zeros)."""
+    from repro.serve.engine import pool_decode_attention, pool_kv_valid
+    num_pages, ps, p, dh = 12, 4, 4, 16
+    rows = POOL_ROWS[case]
+    tables = np.full((len(rows), p), num_pages, np.int32)
+    lens = np.zeros((len(rows),), np.int32)
+    for b, (pages, n) in enumerate(rows):
+        tables[b, :len(pages)] = pages
+        lens[b] = n
+    ks = jax.random.split(jax.random.fold_in(KEY, 7), 3)
+    q = jax.random.normal(ks[0], (len(rows), h, dh))
+    kp = jax.random.normal(ks[1], (num_pages + 1, ps, hkv, dh))
+    vp = jax.random.normal(ks[2], (num_pages + 1, ps, hkv, dh))
+    tables, lens = jnp.asarray(tables), jnp.asarray(lens)
+    valid = pool_kv_valid(tables, lens, num_pages + 1, ps)
+    got = pool_decode_attention(q, kp, vp, valid)
+    want = ref.paged_attention_ref(q, kp, vp, tables, lens)
+    assert got.dtype == want.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-6)
+    # each row attends exactly its own written positions
+    assert np.asarray(valid).sum(axis=1).tolist() == [n for _, n in rows]

@@ -311,6 +311,55 @@ def test_rows_grouped_by_adapter_slot(setup):
         np.testing.assert_array_equal(outs[uid], want)
 
 
+# 3 rows of at most 4 four-slot pages (12 table entries): a pool smaller
+# than the tables and one of 30 pages, more than twice their size; decode
+# reads either in place, trash page included
+@pytest.mark.parametrize("num_pages,kv_slots", [(8, (8 + 1) * 4),
+                                                (30, (30 + 1) * 4)],
+                         ids=["pool_direct", "large_pool"])
+def test_paged_decode_paths_match_dense_under_churn(setup, num_pages,
+                                                    kv_slots):
+    """Ragged requests through 3 rows: rows recycle, pages are freed and
+    re-allocated with stale KV in them (the 8-page pool also preempts a
+    row, which replays). Greedy tokens equal the dense-cache engine's
+    at either pool size; ``decode_kv_slots`` counts the pool's slots."""
+    cfg, params, adapters, prompts = setup
+    mix = [(6, 10), (3, 4), (5, 7), (2, 12), (6, 3), (4, 9), (1, 8)]
+    outs = {}
+    for mode in ("paged", "dense"):
+        extra = ({"page_size": 4, "num_pages": num_pages}
+                 if mode == "paged" else {})
+        engine = ServeEngine(params, cfg, _registry(cfg, adapters),
+                             max_batch=3, max_seq=16, kv_mode=mode,
+                             prefill_chunk=4, **extra)
+        uids = [engine.submit(prompts[i][:n], f"client{i % len(RANKS)}",
+                              max_new_tokens=m)
+                for i, (n, m) in enumerate(mix)]
+        done = engine.run()
+        outs[mode] = [done[u] for u in uids]
+        if mode == "paged":
+            assert engine.metrics.gauge(
+                "serve.decode_kv_slots").value == kv_slots
+            engine.kv.allocator.check()
+    for got, want in zip(outs["paged"], outs["dense"]):
+        np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("use_pallas,kv_slots", [(False, 257 * 16),
+                                                 (True, 8 * 128 * 16)],
+                         ids=["plain", "pallas"])
+def test_decode_kv_slots_at_phi3_serving_shape(setup, use_pallas,
+                                               kv_slots):
+    """The engine shape of the Phi-3-mini serving benchmark (8 rows of
+    2048 tokens, 256 pages of 16): the plain path reads the 257-page
+    pool in place, the Pallas kernel the 8 x 128 table entries."""
+    cfg, params, adapters, _ = setup
+    engine = ServeEngine(params, cfg, _registry(cfg, adapters),
+                         max_batch=8, max_seq=2048, page_size=16,
+                         num_pages=256, use_pallas=use_pallas)
+    assert engine.decode_kv_slots == kv_slots
+
+
 # ---------------------------------------------------------------------------
 # Dense-ring fallback regression (the PR-3 satellite bugfix)
 # ---------------------------------------------------------------------------
