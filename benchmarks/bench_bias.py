@@ -56,7 +56,7 @@ def run(local_steps_grid=(2, 8, 24), quick=False):
             st_ = {"A": f["A"], "B": f["B"], "mask": masks[t]}
             biases.append(float(aggregation_bias(st_, eta, cfg.lora.alpha)))
         out[steps] = float(np.mean(biases))
-        emit(f"bias/local_steps={steps}", 0.0,
+        emit(f"bias/local_steps={steps}",
              f"relative_bias={out[steps]:.4f}")
     return out
 

@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchmarks.common import emit, time_fn
+from benchmarks.common import emit
 from repro.core import agg_engine
 from repro.core import rank as rank_lib
 from repro.fed import messages as msg_lib
@@ -59,20 +59,20 @@ def run(num_clients=100, quick=False):
         out[f"{name}_static_formula"] = static
         assert abs(per_round - static) < 0.01 * static, \
             "serialized payload drifted from the static byte math"
-        emit(f"comm/{name}", 0.0,
+        emit(f"comm/{name}",
              f"bytes_per_client_per_round={per_round:.0f} serialized "
              f"({per_round / out['uniform_r8'] * 100:.0f}% of homogeneous; "
              f"static formula {static:.0f})")
     # bf16 wire: dtype-aware accounting (2 bytes/elt on the same format)
     out["uniform_r8_bf16"] = float(
         _wire_bytes_for_rank(8, LAYERS, jnp.bfloat16))
-    emit("comm/uniform_r8_bf16", 0.0,
+    emit("comm/uniform_r8_bf16",
          f"bytes_per_client_per_round={out['uniform_r8_bf16']:.0f} "
          f"(bf16 payloads: {out['uniform_r8_bf16'] / out['uniform_r8']:.2f}x"
          f" of f32)")
     # naive zero-padding ALSO transmits r_k (padding is server-side), so
     # hlora's comm advantage comes entirely from enabling low-rank clients.
-    emit("comm/hlora_equals_naive_wire_format", 0.0,
+    emit("comm/hlora_equals_naive_wire_format",
          "uplink identical; HLoRA adds zero comm overhead (claim C4)")
 
     # -- engine cross-check: measured downlink on real redistributed trees --
@@ -95,7 +95,6 @@ def run(num_clients=100, quick=False):
         }
     engine = agg_engine.default_engine()
     eta = jnp.ones((k,))
-    agg_us = time_fn(lambda: engine(tree, eta, 16.0)[0])
     redistributed, _ = engine(tree, eta, 16.0)
     # Measured on the engine's actual output: a rank direction costs wire
     # bytes only if the redistributed factors carry nonzero values there —
@@ -116,12 +115,10 @@ def run(num_clients=100, quick=False):
     assert (per_client <= expected).all(), "redistribution leaked past r_k"
     measured = float(per_client.mean())
     out["engine_measured_random_2_8"] = measured
-    emit("comm/engine_measured_random_2_8", agg_us,
+    emit("comm/engine_measured_random_2_8",
          f"bytes_per_client_per_round={measured:.0f} "
          f"(live rank dirs counted on engine output; static formula says "
-         f"{float(expected.mean()):.0f}) "
-         f"(per-round server cost amortized over K={k} clients: "
-         f"{agg_us / k:.0f}us/client)")
+         f"{float(expected.mean()):.0f})")
     out.update(_codec_curve(layers))
     return out
 
@@ -164,7 +161,7 @@ def _codec_curve(layers: int) -> dict:
         slug = spec.replace(":", "")
         out[f"codec_{slug}_bytes"] = float(msg.num_bytes)
         out[f"codec_{slug}_rel_err"] = rel_err(back.adapter)
-        emit(f"comm/codec_{slug}", 0.0,
+        emit(f"comm/codec_{slug}",
              f"bytes={msg.num_bytes} rel_err(ΔW)="
              f"{out[f'codec_{slug}_rel_err']:.2e} "
              f"({msg.num_bytes / out['codec_none_bytes'] * 100:.0f}% of "

@@ -16,7 +16,6 @@ resolved to AggregationStrategy objects by name.
 """
 from __future__ import annotations
 
-import time
 from typing import Dict, List
 
 import numpy as np
@@ -56,7 +55,6 @@ def run(tasks=("mrpc", "rte", "qqp"), seeds=(0, 1), rounds=14,
         base = pretrain_backbone(cfg, sim0)
         for strat, policy, label in STRATEGIES:
             curves = []
-            t0 = time.time()
             for seed in seeds:
                 sim = SimConfig(**{**sim0.__dict__, "seed": seed})
                 if strat == "centralized":
@@ -67,7 +65,7 @@ def run(tasks=("mrpc", "rte", "qqp"), seeds=(0, 1), rounds=14,
                         clients_per_round=4 if quick else 10,
                         strategy=strat, rank_policy=policy,
                         r_min=2, r_max=8, seed=seed)
-                    # curves only — bench_fed owns the wire-byte numbers
+                    # curves only — bench_comm owns the wire-byte numbers
                     h = run_experiment(cfg, sim, scfg, base_params=base,
                                        track_comm=False)
                 curves.append(h["eval_acc"])
@@ -78,14 +76,12 @@ def run(tasks=("mrpc", "rte", "qqp"), seeds=(0, 1), rounds=14,
                 "final": float(np.mean([c[-1] for c in curves])),
                 "best": float(np.mean([max(c) for c in curves])),
                 "mean_last3": float(mean_curve[-3:].mean()),
-                "seconds": time.time() - t0,
             }
             tgt = 0.66
             r2t = rounds_to_target({"round": list(range(len(mean_curve))),
                                     "eval_acc": mean_curve.tolist()}, tgt)
             results[key]["rounds_to_66"] = r2t if r2t is not None else -1
             emit(f"fig3/{task}/{label.replace(' ', '_')}",
-                 results[key]["seconds"] * 1e6 / max(rounds, 1),
                  f"final={results[key]['final']:.4f} "
                  f"best={results[key]['best']:.4f} "
                  f"rounds_to_{tgt}={r2t}")

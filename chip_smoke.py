@@ -221,8 +221,8 @@ def serve_wave(params, cfg, adapters, prompts, new_tokens: int, *,
         reg.register(aid, tree)
     engine = ServeEngine(params, cfg, reg, max_batch=len(prompts),
                          max_seq=prompts.shape[1] + new_tokens,
-                         kv_mode="paged", use_pallas=use_pallas,
-                         cache_dtype=dtype, mesh=mesh)
+                         use_pallas=use_pallas, cache_dtype=dtype,
+                         mesh=mesh)
     names = sorted(adapters)
     secs, toks = [], None
     for _ in range(waves):

@@ -15,9 +15,9 @@ discipline structurally: any write-mode ``open()`` must either
   literal containing ``.tmp``) — the tmp half of the pattern when the
   replace lives a call away.
 
-Append-mode streams (``"a"``) are exempt: the history/dryrun JSONL
-appenders tolerate torn trailing lines by contract (readers drop
-them), which is the right discipline for incremental logs.
+Append-mode streams (``"a"``) are exempt: JSONL appenders tolerate
+torn trailing lines by contract (readers drop them), which is the
+right discipline for incremental logs.
 """
 from __future__ import annotations
 

@@ -13,8 +13,8 @@ call sites on the AST, nothing else.
 Scope is all of ``src/repro`` (the grep only covered serve/fed/obs).
 Sanctioned sites: ``obs/recorder.py`` (the clock owner — allowlisted
 in :data:`~repro.analysis.framework.ALLOWLIST`) and pragma'd lines in
-``launch/dryrun.py`` / ``launch/train.py`` (standalone CLIs reporting
-wall-clock progress with no recorder in scope) and the
+``launch/train.py`` (a standalone CLI reporting wall-clock progress
+with no recorder in scope) and the
 ``core/agg_engine.py`` autotune probe (a one-shot timing *measurement*
 whose result is a backend choice, not a recorded event).
 """

@@ -113,7 +113,7 @@ class ModelConfig:
         """long_500k eligibility: sub-quadratic decode memory.
 
         SSM/hybrid natively; dense/moe/vlm only when a sliding window is
-        configured (we enable one for the long_500k dry-run variant);
+        configured (``launch/inputs.config_for`` adds one for long_500k);
         whisper (a 30 s audio domain) and roberta (no decode step) are
         skipped.
         """

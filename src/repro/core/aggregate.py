@@ -86,7 +86,7 @@ def reconstruct_factored(
     """ΔW' as (P, Q) with P: (..., d_in, K·r_max), Q: (..., K·r_max, d_out).
 
     Never materializes the dense (d_in × d_out) update — the coefficient
-    η_k·s_k is folded into P. Feeds svd_factored (O(d R²), DESIGN.md §3).
+    η_k·s_k is folded into P. Feeds svd_factored (O(d R²)).
     """
     eta = _norm_weights(eta)
     a, b = masked_factors(stacked)
@@ -189,7 +189,7 @@ def aggregate_tree_reference(
     key: Optional[jax.Array] = None,
 ) -> Dict[str, StackedAdapter]:
     """Seed per-target Python loop — un-batched, un-jitted. Kept as the
-    oracle the engine is pinned against (tests + bench_server)."""
+    oracle the engine is pinned against (tests/test_engine.py)."""
     out = {}
     for name in sorted(adapters):
         nm = None if new_masks is None else new_masks[name]

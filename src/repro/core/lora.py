@@ -94,8 +94,8 @@ def apply_lora(
     ``w0`` is the frozen base matrix. The adapter path computes in
     **x.dtype** (adapters keep f32 master copies; they are cast per use).
     Upcasting x to f32 here contaminates the whole backward pass with f32
-    activation cotangents — measured as the dominant collective volume of
-    the sharded train step (EXPERIMENTS.md §Perf iteration 2).
+    activation cotangents — the dominant collective volume of the
+    sharded train step.
     """
     y = x @ w0
     if adapter is None:

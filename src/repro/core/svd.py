@@ -150,7 +150,7 @@ def svd_randomized(
     Exact (to float precision) when rank(w) ≤ r + oversample, which holds
     for HLoRA aggregates with Σ r_k ≤ r + oversample; otherwise the error
     is bounded by the (r+1)-th singular value. Pure matmul + tall-skinny
-    QR — the TPU-native replacement for a LAPACK SVD (DESIGN.md §3).
+    QR — the TPU-native replacement for a LAPACK SVD.
     """
     d_in, d_out = w.shape
     l = min(r + oversample, min(d_in, d_out))

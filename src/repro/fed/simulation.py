@@ -13,8 +13,7 @@ golden-tested to reproduce the pre-refactor loop bit-for-bit; pass
 ``scheduler=SemiSync(...)`` / ``BufferedAsync(...)`` for the other
 modes). It returns a history {round, train_loss, eval_acc, eval_loss,
 downlink_bytes, uplink_bytes, ...} that benchmarks/bench_convergence.py
-turns into Fig. 3 / Table 1 and benchmarks/bench_fed.py into the
-orchestration comparison.
+turns into Fig. 3 / Table 1.
 """
 from __future__ import annotations
 

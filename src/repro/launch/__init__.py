@@ -1,12 +1,6 @@
-"""Launch layer: production meshes, sharding rules, dry-run, train driver.
+"""Launch layer: meshes, sharding rules, abstract inputs, the compile
+cache and the federated training driver."""
+from repro.launch.mesh import (data_axis_size, fsdp_axes, make_host_mesh,
+                               num_chips)
 
-NOTE: do not import repro.launch.dryrun from library code — it sets
-XLA_FLAGS for 512 host devices at import time (dry-run only).
-"""
-from repro.launch.mesh import (HBM_BW, ICI_BW, PEAK_FLOPS_BF16,
-                               data_axis_size, fsdp_axes, make_host_mesh,
-                               make_production_mesh, num_chips)
-
-__all__ = ["make_production_mesh", "make_host_mesh", "fsdp_axes",
-           "data_axis_size", "num_chips", "PEAK_FLOPS_BF16", "HBM_BW",
-           "ICI_BW"]
+__all__ = ["make_host_mesh", "fsdp_axes", "data_axis_size", "num_chips"]

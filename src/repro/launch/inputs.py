@@ -4,7 +4,7 @@
 kind; ``abstract_state`` builds abstract params / optimizer state / caches
 via jax.eval_shape. ``config_for`` applies the per-shape architecture
 variants (sliding-window for dense long-context decode) and ``skip_reason``
-encodes the DESIGN.md skip table.
+names the (architecture, shape) pairs that have no step to build.
 """
 from __future__ import annotations
 
@@ -33,10 +33,10 @@ def config_for(arch: str, shape: InputShape) -> Tuple[ModelConfig, str]:
 
 def skip_reason(cfg: ModelConfig, shape: InputShape) -> Optional[str]:
     if shape.kind == "decode" and not cfg.supports_decode:
-        return "encoder-only: no decode step (DESIGN.md)"
+        return "encoder-only: no decode step"
     if (shape.kind == "decode" and shape.seq_len > 100_000
             and cfg.arch_type == "audio"):
-        return "whisper: full-attention enc-dec, 30s-audio domain (DESIGN.md)"
+        return "whisper: full-attention enc-dec, 30s-audio domain"
     return None
 
 

@@ -1,34 +1,14 @@
-"""Production meshes (TPU v5e target).
-
-Single pod: 16×16 = 256 chips, axes (data, model).
-Multi-pod:  2×16×16 = 512 chips, axes (pod, data, model) — the pod axis
-carries the outermost data parallelism / hierarchical FedAvg reduction.
+"""Meshes the engines shard over: axes (data, model), with an optional
+outer ``pod`` axis that carries the outermost data parallelism.
 
 Functions, not module constants: importing this module must never touch
-jax device state (the dry-run sets XLA_FLAGS before first jax init).
+jax device state (multi-device host tests set XLA_FLAGS before the first
+jax init).
 """
 from __future__ import annotations
 
 import jax
 from jax.sharding import AxisType, Mesh
-
-# Hardware constants for the roofline analysis (TPU v5e).
-PEAK_FLOPS_BF16 = 197e12       # per chip
-HBM_BW = 819e9                 # bytes/s per chip
-ICI_BW = 50e9                  # bytes/s per link
-
-
-def make_production_mesh(*, multi_pod: bool = False, shape=None):
-    """Default 16×16 (or 2×16×16); ``shape`` overrides the (data, model)
-    split at constant chip count — the §Perf mesh-reassignment knob (e.g.
-    (64, 4): more data-parallel, less tensor-parallel => per-device
-    activation-collective volume drops ∝ local batch)."""
-    if shape is None:
-        shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = (("pod", "data", "model") if len(shape) == 3
-            else ("data", "model"))
-    return jax.make_mesh(shape, axes, (AxisType.Auto,) * len(axes))
-
 
 def make_host_mesh(data: int = 1, model: int = 1):
     """Host-CPU mesh with the production axis names at test sizes.

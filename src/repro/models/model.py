@@ -132,7 +132,7 @@ def loss_fn(params, batch, cfg: ModelConfig, *, remat: bool = True,
     # take_along_axis. The gather form forces GSPMD to all-gather the
     # (B,S,V) logp when vocab is model-sharded (67 GB/device for gemma
     # train_4k); this form reduces over the local vocab shard and
-    # all-reduces only (B,S) scalars. See EXPERIMENTS.md §Perf iteration 1.
+    # all-reduces only (B,S) scalars.
     lg = logits.astype(jnp.float32)
     lse = jax.nn.logsumexp(lg, axis=-1)                          # (B, S)
     vocab_iota = jnp.arange(lg.shape[-1], dtype=labels.dtype)

@@ -8,8 +8,8 @@ of the full (B,H,Sq,Skv) logits every layer (309 GB/device for gemma
 train_4k). Constraining q/k/v to head-aligned shardings replaces that
 with one cheap activation reshard.
 
-Disabled by default (the baseline); the dry-run enables it for the
-optimized variant. Requires a mesh context at trace time.
+Disabled by default; ``enable()`` turns it on for the optimized
+variant. Requires a mesh context at trace time.
 """
 from __future__ import annotations
 

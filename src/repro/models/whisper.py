@@ -132,7 +132,7 @@ def forward(params, tokens, cfg: ModelConfig, *, frames=None, remat=True,
 
 def init_cache(cfg: ModelConfig, batch: int, max_seq: int, dtype=jnp.bfloat16):
     """Self-attn cache + precomputed cross K/V (filled at prefill from the
-    encoder output; zeros here — dry-run provides ShapeDtypeStructs)."""
+    encoder output; zeros here)."""
     hd = cfg.resolved_head_dim
     return {
         "self": init_kv_cache(cfg.num_layers, batch, max_seq,

@@ -14,8 +14,7 @@ an on-call engineer would want to see it:
 * the full metrics summary (``MetricsRegistry.summary_text``).
 
 ``snapshot_text`` is the same content as a terminal block — the
-``summary_text``-style quick look ``bench_obs`` and the example
-scenario print.
+``summary_text``-style quick look the example scenario prints.
 
 Writes are atomic (tmp + ``os.replace``), like every exporter here.
 """

@@ -9,8 +9,8 @@ inside every layer the LoRA path is the BGMV gather
 
 (Pallas ``kernels/bgmv.py`` on TPU, the gather-einsum oracle elsewhere).
 
-KV state is **paged** by default (``serve/pages.py``): rows own page
-lists in a global pool instead of dense ``(max_seq, Hkv, Dh)`` strips,
+KV state is **paged** (``serve/pages.py``): rows own page lists in a
+global pool instead of dense ``(max_seq, Hkv, Dh)`` strips,
 so admission is gated by *free pages* — what traffic actually uses —
 rather than by the worst-case ``max_seq``. The scheduler defers
 admission while the pool is dry, extends a row's page list as its decode
@@ -42,11 +42,6 @@ Everything is value updates against fixed shapes — page tables, page
 extensions, admissions, hot-swaps, speculative windows, rollbacks — so
 ``trace_count`` stays flat at one trace per jitted step (decode +
 prefill + verify) for the engine's lifetime.
-
-``kv_mode="dense"`` keeps the PR-2 dense ring cache as a fallback; its
-insert path *drops* writes past the ring instead of silently wrapping
-(which corrupted attention for any row outliving its ring), and the
-scheduler raises before that can happen.
 """
 from __future__ import annotations
 
@@ -63,8 +58,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs.base import ModelConfig
 from repro.launch import mesh as mesh_lib
 from repro.launch import sharding as shard_rules
-from repro.models.common import (_act, _repeat_kv, attention, init_kv_cache,
-                                 rope, sinusoidal_positions)
+from repro.models.common import (_act, _repeat_kv, attention, rope,
+                                 sinusoidal_positions)
 from repro.models.transformer import norm
 from repro.obs import NULL_RECORDER, MetricsRegistry
 from repro.serve.pages import PagedKV
@@ -121,25 +116,9 @@ def _apply_slab_lora(x, w0, slab, idx, alpha, use_pallas: bool):
     return y + (scale[idx][:, None, None] * lo).astype(y.dtype)
 
 
-def _cache_insert_rows(lc, k_new, v_new, pos):
-    """Per-row dense-ring insert: row i's token goes to slot pos[i].
-
-    Positions at or past the ring (pos >= slots) are **dropped**, not
-    wrapped: wrapping overwrote the row's oldest live entries while the
-    validity mask still reported them current — silently corrupted
-    attention for any row that outlived its ring. The host scheduler
-    raises before this can happen (see ``step_batch``); ``mode='drop'``
-    makes the traced path fail safe rather than fail wrong."""
-    rows = jnp.arange(pos.shape[0])
-    return {
-        "k": lc["k"].at[rows, pos].set(k_new[:, 0], mode="drop"),
-        "v": lc["v"].at[rows, pos].set(v_new[:, 0], mode="drop"),
-        "pos": lc["pos"].at[rows, pos].set(pos, mode="drop"),
-    }
-
-
 # ---------------------------------------------------------------------------
-# Shared per-layer blocks (decode and prefill differ only in KV handling)
+# Shared per-layer blocks (decode, prefill and verify differ only in KV
+# handling)
 # ---------------------------------------------------------------------------
 
 def _layer_qkv(x, lp, slab, idx, pos, cfg: ModelConfig, use_pallas):
@@ -187,23 +166,6 @@ def _layer_out(x, o, lp, slab, idx, cfg: ModelConfig, use_pallas):
     if cfg.use_bias and "b2" in mp:
         y = y + mp["b2"]
     return x + y
-
-
-def _layer_decode_dense(x, lp, slab, lc, idx, pos, cfg: ModelConfig,
-                        use_pallas: bool):
-    """One token through one layer against the dense ring cache."""
-    bsz = x.shape[0]
-    hd = cfg.resolved_head_dim
-    _, q, k, v = _layer_qkv(x, lp, slab, idx, pos[:, None], cfg, use_pallas)
-    lc = _cache_insert_rows(lc, k, v, pos)
-    # Validity-masked attention: each row sees exactly its own cached
-    # prefix (stale slots are pos=-1, recycled rows were reset) — the
-    # causal structure is in the mask, not a shared scalar position.
-    valid = (lc["pos"] >= 0) & (lc["pos"] <= pos[:, None])
-    o = attention(q, lc["k"], lc["v"], causal=False, window=None,
-                  kv_positions=lc["pos"], kv_valid=valid)
-    o = o.reshape(bsz, 1, cfg.num_heads * hd)
-    return _layer_out(x, o, lp, slab, idx, cfg, use_pallas), lc
 
 
 def pool_kv_valid(tables, lens, pool_pages: int, page_size: int):
@@ -343,12 +305,11 @@ class ServeEngine:
     paging, preemption, token routing, finish/recycle), everything
     per-token is on device.
 
-    kv_mode="paged" (default): a global page pool; per-request capacity
-    is ``ceil((prompt + max_new) / page_size)`` pages, admission waits
-    for free pages, decode extends page lists in place, and prompt
-    prefill runs ``prefill_chunk`` tokens per dispatch.
-    kv_mode="dense": the PR-2 per-row ring cache (one ``max_seq`` strip
-    per row, token-at-a-time prefill) — the memory/latency baseline.
+    KV state is one global page pool: per-request capacity is
+    ``ceil((prompt + max_new) / page_size)`` pages, admission waits for
+    free pages, decode extends page lists in place, and prompt prefill
+    runs ``prefill_chunk`` tokens per dispatch. ``kv_mode`` accepts only
+    ``"paged"``, the one cache there is.
     """
 
     def __init__(self, params, cfg: ModelConfig, registry, *,
@@ -369,22 +330,14 @@ class ServeEngine:
                 f"{cfg.arch_type!r}")
         if cfg.num_experts:
             raise NotImplementedError("MoE serving not wired yet")
-        if kv_mode not in ("paged", "dense"):
-            raise ValueError(f"unknown kv_mode {kv_mode!r}")
-        if drafter is not None and kv_mode != "paged":
+        if kv_mode != "paged":
             raise ValueError(
-                "speculative decode needs the paged KV cache (rollback "
-                "is a page-table operation); kv_mode='dense' has no "
-                "draft-verify path")
+                f"kv_mode must be 'paged' (the only KV cache), got "
+                f"{kv_mode!r}")
         if drafter is not None and spec_k < 1:
             raise ValueError(f"spec_k must be >= 1, got {spec_k}")
         self.mesh = mesh_lib.auto_axes(mesh)
         self.num_shards = mesh_lib.data_axis_size(mesh)
-        if self.num_shards > 1 and kv_mode != "paged":
-            raise ValueError(
-                "mesh-sharded serving needs the paged KV cache "
-                "(per-device page sub-pools); kv_mode='dense' is "
-                "single-device only")
         if self.num_shards > 1 and max_batch % self.num_shards:
             raise ValueError(
                 f"max_batch {max_batch} must divide over the mesh's "
@@ -394,7 +347,6 @@ class ServeEngine:
         self.registry = registry
         self.max_batch = int(max_batch)
         self.max_seq = int(max_seq)
-        self.kv_mode = kv_mode
         self.drafter = drafter
         self.spec_k = int(spec_k)
         if use_pallas is None:
@@ -416,44 +368,36 @@ class ServeEngine:
         self.slo_ttft_s: Dict[str, float] = dict(slo_ttft_s or {})
         self._slo_classes: set = set()
         self.trace_count = 0
-        if kv_mode == "paged":
-            self.page_size = int(page_size)
-            pages_per_row = -(-self.max_seq // self.page_size)
-            if num_pages is None:
-                # Same worst-case capacity as the dense cache; the win
-                # comes from sizing num_pages to *traffic* instead.
-                num_pages = self.max_batch * pages_per_row
-            self.kv = PagedKV(cfg.num_layers, int(num_pages),
-                              self.page_size, pages_per_row,
-                              self.max_batch, cfg.num_kv_heads,
-                              cfg.resolved_head_dim, dtype=cache_dtype,
-                              num_shards=self.num_shards,
-                              metrics=self.metrics,
-                              name=f"{self.name}.pages")
-            self.prefill_chunk = max(1, int(prefill_chunk))
-            if self.num_shards > 1:
-                self._place_state()
-                step, verify, prefill = self._shard_mapped_steps()
-            else:
-                step, verify, prefill = (self._paged_step_impl,
-                                         self._verify_impl,
-                                         self._prefill_impl)
-            self._step = jax.jit(step)
-            self._prefill = jax.jit(prefill)
-            self._verify = jax.jit(verify)
-            # KV slots decode attention reads per layer: plain attention
-            # reads each shard's sub-pool (its trash page included) in
-            # place, the Pallas kernel every row's table.
-            self.decode_kv_slots = self.kv.num_shards * self.page_size * (
-                self.kv.rows_per_shard * pages_per_row if self.use_pallas
-                else self.kv.pages_per_shard + 1)
+        self.page_size = int(page_size)
+        pages_per_row = -(-self.max_seq // self.page_size)
+        if num_pages is None:
+            # Worst case: every row at max_seq; the win comes from
+            # sizing num_pages to *traffic* instead.
+            num_pages = self.max_batch * pages_per_row
+        self.kv = PagedKV(cfg.num_layers, int(num_pages),
+                          self.page_size, pages_per_row,
+                          self.max_batch, cfg.num_kv_heads,
+                          cfg.resolved_head_dim, dtype=cache_dtype,
+                          num_shards=self.num_shards,
+                          metrics=self.metrics,
+                          name=f"{self.name}.pages")
+        self.prefill_chunk = max(1, int(prefill_chunk))
+        if self.num_shards > 1:
+            self._place_state()
+            step, verify, prefill = self._shard_mapped_steps()
         else:
-            self.cache = init_kv_cache(cfg.num_layers, self.max_batch,
-                                       self.max_seq, cfg.num_kv_heads,
-                                       cfg.resolved_head_dim,
-                                       dtype=cache_dtype)
-            self._step = jax.jit(self._dense_step_impl)
-            self._reset = jax.jit(self._reset_impl)
+            step, verify, prefill = (self._paged_step_impl,
+                                     self._verify_impl,
+                                     self._prefill_impl)
+        self._step = jax.jit(step)
+        self._prefill = jax.jit(prefill)
+        self._verify = jax.jit(verify)
+        # KV slots decode attention reads per layer: plain attention
+        # reads each shard's sub-pool (its trash page included) in
+        # place, the Pallas kernel every row's table.
+        self.decode_kv_slots = self.kv.num_shards * self.page_size * (
+            self.kv.rows_per_shard * pages_per_row if self.use_pallas
+            else self.kv.pages_per_shard + 1)
         self._queue: deque = deque()
         self._rows: List[Optional[dict]] = [None] * self.max_batch
         self._done: Dict[str, np.ndarray] = {}
@@ -469,7 +413,7 @@ class ServeEngine:
         self.drafted_tokens = 0
         self.accepted_tokens = 0
         self.rollback_pages = 0
-        # distinct adapter slots among active rows at the last paged
+        # distinct adapter slots among active rows at the last
         # dispatch — rows are sorted/grouped by slot before the BGMV
         # gather (the first move toward SGMV tile reuse)
         self.bgmv_groups = 0
@@ -537,16 +481,12 @@ class ServeEngine:
     # -- introspection ------------------------------------------------------
 
     def kv_cache_bytes(self) -> int:
-        """Device bytes held by the KV state (pool or dense cache)."""
-        if self.kv_mode == "paged":
-            return self.kv.nbytes()
-        return sum(int(x.nbytes) for x in jax.tree.leaves(self.cache))
+        """Device bytes held by the KV page pool."""
+        return self.kv.nbytes()
 
     def row_capacity(self) -> int:
         """Max tokens (prompt + generation) one request may ever hold."""
-        if self.kv_mode == "paged":
-            return self.kv.row_capacity()
-        return self.max_seq
+        return self.kv.row_capacity()
 
     # -- mesh plumbing ------------------------------------------------------
 
@@ -633,23 +573,6 @@ class ServeEngine:
     def _logits(self, params, x):
         head = params.get("lm_head")
         return x @ (head if head is not None else params["embed"].T)
-
-    def _dense_step_impl(self, params, slabs, cache, idx, tokens, pos):
-        """tokens: (B,1) int32, pos: (B,) int32, idx: (B,) int32 slab slots
-        -> (logits (B,V), cache)."""
-        self.trace_count += 1   # side effect fires at trace time only
-        x = self._embed(params, tokens, pos[:, None])
-
-        def scan_body(carry, xs):
-            lp, slab_l, lc = xs
-            y, new_lc = _layer_decode_dense(carry, lp, slab_l, lc, idx, pos,
-                                            self.cfg, self.use_pallas)
-            return y, new_lc
-
-        x, new_cache = lax.scan(scan_body, x,
-                                (params["layers"], slabs, cache))
-        x = norm(x, params["final_norm"])
-        return self._logits(params, x[:, 0, :]), new_cache
 
     def _decode_kv_valid(self, tables, lens):
         """Built once per decode step, outside the layer scan: the
@@ -752,12 +675,6 @@ class ServeEngine:
         x = norm(x, params["final_norm"])
         return self._logits(params, x[0]), new_pools
 
-    @staticmethod
-    def _reset_impl(cache, row_mask):
-        """Invalidate the KV prefix of recycled rows (value-only update)."""
-        pos = jnp.where(row_mask[None, :, None], -1, cache["pos"])
-        return {**cache, "pos": pos}
-
     # -- scheduler ----------------------------------------------------------
 
     def submit(self, prompt, adapter_id: str,
@@ -768,11 +685,9 @@ class ServeEngine:
             raise ValueError("empty prompt")
         total = prompt.size + max_new_tokens
         if total > self.row_capacity():
-            what = (f"{self.kv.pages_for(total)} pages" if
-                    self.kv_mode == "paged" else f"max_seq {self.max_seq}")
             raise ValueError(
                 f"prompt+generation {total} exceeds per-request capacity "
-                f"{self.row_capacity()} ({what})")
+                f"{self.row_capacity()} ({self.kv.pages_for(total)} pages)")
         if not self.registry.has(adapter_id):
             raise KeyError(f"unknown adapter {adapter_id!r}")
         uid = f"req{self._uid}"
@@ -795,8 +710,7 @@ class ServeEngine:
     def _finish(self, row: int, req: dict) -> None:
         self._done[req["uid"]] = np.asarray(req["out"], np.int32)
         self.registry.release(req["adapter"])
-        if self.kv_mode == "paged":
-            self.kv.release(row)
+        self.kv.release(row)
         self._rows[row] = None
         if self.rec.enabled and "_ts" in req:
             dur = self.rec.now() - req["_ts"]
@@ -833,30 +747,24 @@ class ServeEngine:
 
     def _admit(self) -> int:
         admitted = 0
-        freed = np.zeros((self.max_batch,), bool)
-        any_freed = False
         free_rows = [r for r in range(self.max_batch)
                      if self._rows[r] is None]
         while self._queue and free_rows:
             head = self._queue[0]
-            need = 0
-            if self.kv_mode == "paged":
-                # Page-gated admission: cover the prompt plus the first
-                # generated token; later growth extends. A row's pages
-                # come from its own shard's sub-pool, so pick the first
-                # free row whose shard can cover the head (with one
-                # shard this is exactly the old first-free-row scan).
-                need = self.kv.pages_for(head["prompt"].size + 1)
-                row = next((r for r in free_rows
-                            if self.kv.free_count_for(r) >= need), None)
-                if row is None:
-                    self.deferrals += 1
-                    if self.rec.enabled:
-                        self.rec.instant("defer", self._track(head),
-                                         need_pages=int(need))
-                    break   # FCFS: wait for pages, don't starve head
-            else:
-                row = free_rows[0]
+            # Page-gated admission: cover the prompt plus the first
+            # generated token; later growth extends. A row's pages come
+            # from its own shard's sub-pool, so pick the first free row
+            # whose shard can cover the head (with one shard this is
+            # exactly the first-free-row scan).
+            need = self.kv.pages_for(head["prompt"].size + 1)
+            row = next((r for r in free_rows
+                        if self.kv.free_count_for(r) >= need), None)
+            if row is None:
+                self.deferrals += 1
+                if self.rec.enabled:
+                    self.rec.instant("defer", self._track(head),
+                                     need_pages=int(need))
+                break   # FCFS: wait for pages, don't starve head
             try:
                 slot = self.registry.acquire(head["adapter"])
             except RuntimeError:
@@ -870,17 +778,11 @@ class ServeEngine:
                 self.rec.instant(
                     "replay" if req.get("_replays") else "admit",
                     self._track(req), row=int(row))
-            if self.kv_mode == "paged":
-                if not self.kv.admit(row, need):   # free_count said yes
-                    raise RuntimeError(
-                        f"page accounting violated: admission of row "
-                        f"{row} failed after the free-count check")
-                self._prefill_row(row, req)
-            else:
-                freed[row] = True
-                any_freed = True
-        if any_freed:
-            self.cache = self._reset(self.cache, jnp.asarray(freed))
+            if not self.kv.admit(row, need):   # free_count said yes
+                raise RuntimeError(
+                    f"page accounting violated: admission of row "
+                    f"{row} failed after the free-count check")
+            self._prefill_row(row, req)
         return admitted
 
     def _prefill_row(self, row: int, req: dict) -> None:
@@ -980,7 +882,7 @@ class ServeEngine:
 
     def _slot_order(self, idx: np.ndarray, active_mask: np.ndarray):
         """Stable permutation grouping batch rows by adapter slot
-        (inactive rows last) — applied to every per-row input of a paged
+        (inactive rows last) — applied to every per-row input of a
         dispatch, so rows sharing an adapter sit adjacent for the BGMV
         gather (the precondition for SGMV-style tile reuse). Host-side
         values only: same shapes every step, nothing retraces. Returns
@@ -989,7 +891,7 @@ class ServeEngine:
         ``bgmv_groups``."""
         key = np.where(active_mask, idx, np.iinfo(np.int32).max)
         self.bgmv_groups = len(set(idx[active_mask].tolist()))
-        if self.kv_mode == "paged" and self.kv.num_shards > 1:
+        if self.kv.num_shards > 1:
             # Rows must stay on the shard owning their pages: sort
             # within each contiguous shard block, never across.
             rps = self.kv.rows_per_shard
@@ -1007,20 +909,18 @@ class ServeEngine:
         """Admit (+prefill), page, run one decode (or draft+verify)
         step, harvest/recycle."""
         admitted = self._admit()
-        if self.kv_mode == "paged":
-            look = None
-            if self.drafter is not None:
-                look = {i: self._spec_window(r)
-                        for i, r in enumerate(self._rows) if r is not None}
-            self._ensure_pages(look)
+        look = None
+        if self.drafter is not None:
+            look = {i: self._spec_window(r)
+                    for i, r in enumerate(self._rows) if r is not None}
+        self._ensure_pages(look)
         active = [(i, r) for i, r in enumerate(self._rows) if r is not None]
         if not active:
             # admitted rows may have finished inside _admit (prefill +
             # max_new=1): that is progress, not a stall
             if self._queue and admitted == 0:
-                if self.kv_mode == "paged" and \
-                        self.kv.max_free_count() < self.kv.pages_for(
-                            self._queue[0]["prompt"].size + 1):
+                if self.kv.max_free_count() < self.kv.pages_for(
+                        self._queue[0]["prompt"].size + 1):
                     # no row active yet pages are missing: pinned by
                     # someone outside this engine
                     raise RuntimeError(
@@ -1040,14 +940,10 @@ class ServeEngine:
         idx = np.zeros((self.max_batch,), np.int32)
         lens = np.zeros((self.max_batch,), np.int32)
         for i, req in active:
+            # rows join the batch past their prompt (prefill runs at
+            # admission), so the input token is always the last sample
             t = req["t"]
-            if self.kv_mode == "dense" and t >= self.max_seq:
-                raise RuntimeError(
-                    f"row {i} reached position {t} >= max_seq "
-                    f"{self.max_seq}: the dense ring would wrap and "
-                    f"corrupt attention (writes are dropped instead)")
-            tokens[i, 0] = req["prompt"][t] if t < req["prompt"].size \
-                else req["out"][-1]
+            tokens[i, 0] = req["out"][-1]
             pos[i] = t
             idx[i] = req["slot"]
             lens[i] = t + 1
@@ -1056,30 +952,21 @@ class ServeEngine:
         # a true step latency (host + device)
         with rec.span("serve.decode_step", self._engine_track,
                       batch=len(active)) as step:
-            if self.kv_mode == "paged":
-                perm, inv = self._slot_order(idx, lens > 0)
-                logits, self.kv.pools = self._step(
-                    self.params, self.registry.slabs(), self.kv.pools,
-                    jnp.asarray(self.kv.tables[perm]),
-                    jnp.asarray(idx[perm]), jnp.asarray(tokens[perm]),
-                    jnp.asarray(pos[perm]), jnp.asarray(lens[perm]))
-                nxt = np.asarray(jnp.argmax(logits, axis=-1), np.int32)[inv]
-            else:
-                logits, self.cache = self._step(
-                    self.params, self.registry.slabs(), self.cache,
-                    jnp.asarray(idx), jnp.asarray(tokens), jnp.asarray(pos))
-                nxt = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
+            perm, inv = self._slot_order(idx, lens > 0)
+            logits, self.kv.pools = self._step(
+                self.params, self.registry.slabs(), self.kv.pools,
+                jnp.asarray(self.kv.tables[perm]),
+                jnp.asarray(idx[perm]), jnp.asarray(tokens[perm]),
+                jnp.asarray(pos[perm]), jnp.asarray(lens[perm]))
+            nxt = np.asarray(jnp.argmax(logits, axis=-1), np.int32)[inv]
         if rec.enabled:
             self.metrics.histogram(
                 f"{self.name}.decode_step_s").observe(step.seconds)
         self.steps += 1
         for i, req in active:
             req["t"] += 1
-            if req["t"] >= req["prompt"].size:       # past prefill: sample
-                req["out"].append(int(nxt[i]))
-                self.tokens_generated += 1
-                if rec.enabled:
-                    self._note_first_token(req)
+            req["out"].append(int(nxt[i]))
+            self.tokens_generated += 1
             if len(req["out"]) >= req["max_new"]:    # finished: recycle row
                 self._finish(i, req)
 
@@ -1103,7 +990,7 @@ class ServeEngine:
                 f"drafter proposed {props.shape}, expected "
                 f"{(len(active), self.spec_k)}")
         for j, (i, req) in enumerate(active):
-            # paged rows join the batch past their prompt (prefill runs
+            # rows join the batch past their prompt (prefill runs
             # at admission), so the context token is always a sample
             k_b = self._spec_window(req)
             tokens[i, 0] = req["out"][-1]

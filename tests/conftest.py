@@ -1,8 +1,8 @@
 import jax
 import pytest
 
-# Tests run on the single host CPU device (the dry-run, and only the
-# dry-run, forces 512 placeholder devices — see launch/dryrun.py).
+# Tests run on the single host CPU device; the multi-device mesh tests
+# force 8 host devices in a child process (``host_mesh_env`` below).
 jax.config.update("jax_enable_x64", False)
 
 

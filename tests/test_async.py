@@ -70,34 +70,6 @@ def test_async_drops_too_stale(setup):
     assert server.submit(0, ad, ver) is False  # tau=5 > 1 -> dropped
 
 
-def test_hlo_parser_trip_counts():
-    from repro.launch.dryrun import parse_collectives
-    hlo = """
-HloModule test
-
-%body.1 (p: (s32[], f32[8])) -> (s32[], f32[8]) {
-  %ag = f32[64,128]{1,0} all-gather(%x), dimensions={0}
-  ROOT %t = tuple()
-}
-
-%cond.2 (p: (s32[], f32[8])) -> pred[] {
-  %c = s32[] constant(7)
-  ROOT %cmp = pred[] compare(%i, %c), direction=LT
-}
-
-ENTRY %main (a: f32[4]) -> f32[4] {
-  %ar = f32[32,32]{1,0} all-reduce(%a), to_apply=%add.3
-  %w = (s32[], f32[8]) while(%init), condition=%cond.2, body=%body.1
-  ROOT %r = f32[4] copy(%a)
-}
-"""
-    bytes_, counts = parse_collectives(hlo)
-    assert counts["all-reduce"] == 1
-    assert bytes_["all-reduce"] == 32 * 32 * 4
-    assert counts["all-gather"] == 7          # body × trip count
-    assert bytes_["all-gather"] == 7 * 64 * 128 * 4
-
-
 def test_shard_hints_noop_when_disabled():
     from repro.models import shard_hints
     shard_hints.disable()

@@ -67,15 +67,16 @@ def test_script_alone_fails(tmp_path):
 
 
 def test_imports_stay_off_the_forced_device_launchers():
-    """The dry-run and HLO inspector force 512 host devices as they are
-    imported; the chip path must never pull them in."""
+    """The chip path imports the serving and federated library code and
+    nothing host-side: no benchmark runner and no CLI driver."""
     code = ("import json, sys; import chip_smoke; print(json.dumps("
-            "sorted(m for m in sys.modules if m.startswith('repro.'))))")
+            "sorted(m for m in sys.modules if m.split('.')[0] in "
+            "('repro', 'benchmarks', 'perfbench'))))")
     proc = subprocess.run([sys.executable, "-c", code], cwd=ROOT,
                           env=_cpu_env(), capture_output=True, text=True,
                           timeout=300)
     assert proc.returncode == 0, proc.stderr[-2000:]
     mods = json.loads(proc.stdout.splitlines()[-1])
     assert "repro.serve" in mods and "repro.fed.session" in mods
-    assert "repro.launch.dryrun" not in mods
-    assert "repro.launch.inspect_hlo" not in mods
+    assert "repro.launch.train" not in mods
+    assert not [m for m in mods if not m.startswith("repro")], mods

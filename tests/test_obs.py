@@ -455,6 +455,90 @@ def test_fed_default_session_records_nothing():
         sum(sess.comm_log["downlink"]) > 0
 
 
+def test_sync_round_observes_round_seconds():
+    """Each recorded ``SyncRound`` round lands one ``fed.round_s``
+    observation beside its measured wire bytes; an unrecorded session
+    reads no clock, so its histogram stays empty."""
+    from repro.fed import SyncRound
+    cfg = get_reduced("roberta-large")
+    scfg = ServerConfig(num_clients=4, clients_per_round=2,
+                        strategy="hlora", rank_policy="random",
+                        r_min=2, r_max=8, seed=0)
+    base = model_lib.init_params(jax.random.PRNGKey(6), cfg)
+
+    def train(frozen, trainable, masks, batches):
+        return trainable, np.zeros(len(batches), np.float32)
+
+    def data_fn(cohort, rnd):
+        return np.asarray(cohort)
+    for rec, want in ((Recorder(), 2), (None, 0)):
+        metrics = MetricsRegistry()
+        sess = FedSession(cfg, scfg, base, recorder=rec, metrics=metrics)
+        SyncRound().run(sess, train, data_fn, 2)
+        h = metrics.histogram("fed.round_s")
+        assert h.count == want
+        if want:
+            assert h.percentile(50) > 0
+        assert metrics.counter("fed.downlink_bytes").value > 0
+
+
+def test_serve_and_fed_share_one_recorder(serve_setup, tmp_path):
+    """A serving wave and a federated round record into ONE recorder and
+    registry: the merged events carry both sides' spans, export to a
+    valid Chrome trace, round-trip through JSONL, fold into the series
+    the SLOs read, evaluate clean under generous objectives, and render
+    into the ops report."""
+    cfg, params, adapters, prompts = serve_setup
+    rec, metrics = Recorder(), MetricsRegistry()
+    reg = AdapterRegistry(cfg, capacity=2)
+    for i in range(2):
+        reg.register(f"client{i}", adapters[f"client{i}"])
+    engine = ServeEngine(params, cfg, reg, max_batch=2,
+                         max_seq=PROMPT_LEN + 4, page_size=4,
+                         prefill_chunk=4, recorder=rec, metrics=metrics)
+    for i in range(2):
+        engine.submit(prompts[i], f"client{i}", max_new_tokens=4)
+    engine.run()
+    per_request = metrics.histogram("serve.request_tok_s")
+    assert per_request.count == 2 and per_request.percentile(50) > 0
+    fcfg = get_reduced("roberta-large")
+    scfg = ServerConfig(num_clients=4, clients_per_round=2,
+                        strategy="hlora", rank_policy="random",
+                        r_min=2, r_max=8, seed=0)
+    sess = FedSession(fcfg, scfg,
+                      model_lib.init_params(jax.random.PRNGKey(1), fcfg),
+                      recorder=rec, metrics=metrics)
+    cohort = sess.sample_cohort()
+    stacked, heads = sess.broadcast_cohort(cohort)
+    tree, up_heads = sess.collect_updates(cohort, stacked,
+                                          heads if heads else None)
+    sess.aggregate_round(tree, cohort, stacked_heads=up_heads)
+
+    events = rec.events()
+    names = {e[1] for e in events}
+    for want in ("submit", "serve.prefill_chunk", "serve.decode_step",
+                 "finish", "fed.broadcast", "fed.collect", "fed.aggregate"):
+        assert want in names, f"missing {want!r} events in the trace"
+    validate_chrome_trace(chrome_trace(events))
+    path = str(tmp_path / "both.jsonl")
+    write_jsonl(events, path)
+    assert read_jsonl(path) == events
+    store = SeriesStore(bucket_s=0.25)
+    store.fold(events)
+    assert store.has("first_token.ttft_s")
+    assert store.has("span.fed.aggregate")
+    slo = SLOMonitor([
+        Objective("serve_ttft", series="first_token.ttft_s",
+                  threshold=60.0, target=0.9),
+        Objective("fed_aggregate", series="span.fed.aggregate",
+                  threshold=60.0, target=0.9)], recorder=rec)
+    slo.fold(events)
+    states = slo.evaluate()
+    assert set(states) == {"serve_ttft", "fed_aggregate"}
+    assert not any(state.in_violation for state in states.values())
+    html = render_html(store=store, slo=slo, metrics=metrics)
+    assert "first_token.ttft_s" in html and "span.fed.aggregate" in html
+
 # ---------------------------------------------------------------------------
 # spans in a running profiler capture
 # ---------------------------------------------------------------------------

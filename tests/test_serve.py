@@ -5,11 +5,9 @@ heterogeneous-rank multi-LoRA decode vs the per-request merged-weight
 oracle, continuous batching with row recycling, and retrace-free
 hot-swap.
 
-The engine defaults to the paged KV cache with chunked prefill
-(PR 3), so these tests pin that path; the retained dense ring cache is
-covered explicitly (``kv_mode="dense"``), including the wrap-instead-
-of-corrupt regression. A paged engine traces exactly twice: once for
-the chunked-prefill step, once for the decode step.
+The engine's one KV cache is the page pool with chunked prefill. A
+paged engine traces exactly twice: once for the chunked-prefill step,
+once for the decode step.
 """
 import jax
 import numpy as np
@@ -176,24 +174,20 @@ def test_submit_rejections(setup):
 # Paged KV specifics
 # ---------------------------------------------------------------------------
 
-def test_paged_matches_dense_and_oracle(setup):
-    """The paged engine, the dense fallback, and the merged-weight oracle
-    all agree token-for-token on the same traffic."""
+def test_paged_matches_oracle(setup):
+    """Small pages and chunks (4 slots, 4-token prefill chunks): the
+    paged engine and the merged-weight oracle agree token-for-token."""
     cfg, params, adapters, prompts = setup
-    outs = {}
-    for mode in ("paged", "dense"):
-        engine = ServeEngine(params, cfg, _registry(cfg, adapters),
-                             max_batch=4, max_seq=PROMPT_LEN + STEPS,
-                             kv_mode=mode, page_size=4, prefill_chunk=4)
-        uids = [engine.submit(prompts[i], f"client{i % len(RANKS)}",
-                              max_new_tokens=STEPS) for i in range(4)]
-        done = engine.run()
-        outs[mode] = [done[u] for u in uids]
-    for i in range(4):
+    engine = ServeEngine(params, cfg, _registry(cfg, adapters),
+                         max_batch=4, max_seq=PROMPT_LEN + STEPS,
+                         page_size=4, prefill_chunk=4)
+    uids = [engine.submit(prompts[i], f"client{i % len(RANKS)}",
+                          max_new_tokens=STEPS) for i in range(4)]
+    done = engine.run()
+    for i, uid in enumerate(uids):
         want = merged_greedy(params, cfg, prompts[i],
                              adapters[f"client{i % len(RANKS)}"], STEPS)
-        np.testing.assert_array_equal(outs["paged"][i], want)
-        np.testing.assert_array_equal(outs["dense"][i], want)
+        np.testing.assert_array_equal(done[uid], want)
 
 
 def test_paged_oversubscription_defers_and_preempts(setup):
@@ -317,32 +311,27 @@ def test_rows_grouped_by_adapter_slot(setup):
 @pytest.mark.parametrize("num_pages,kv_slots", [(8, (8 + 1) * 4),
                                                 (30, (30 + 1) * 4)],
                          ids=["pool_direct", "large_pool"])
-def test_paged_decode_paths_match_dense_under_churn(setup, num_pages,
-                                                    kv_slots):
+def test_paged_decode_paths_match_oracle_under_churn(setup, num_pages,
+                                                     kv_slots):
     """Ragged requests through 3 rows: rows recycle, pages are freed and
     re-allocated with stale KV in them (the 8-page pool also preempts a
-    row, which replays). Greedy tokens equal the dense-cache engine's
+    row, which replays). Greedy tokens equal the merged-weight oracle's
     at either pool size; ``decode_kv_slots`` counts the pool's slots."""
     cfg, params, adapters, prompts = setup
     mix = [(6, 10), (3, 4), (5, 7), (2, 12), (6, 3), (4, 9), (1, 8)]
-    outs = {}
-    for mode in ("paged", "dense"):
-        extra = ({"page_size": 4, "num_pages": num_pages}
-                 if mode == "paged" else {})
-        engine = ServeEngine(params, cfg, _registry(cfg, adapters),
-                             max_batch=3, max_seq=16, kv_mode=mode,
-                             prefill_chunk=4, **extra)
-        uids = [engine.submit(prompts[i][:n], f"client{i % len(RANKS)}",
-                              max_new_tokens=m)
-                for i, (n, m) in enumerate(mix)]
-        done = engine.run()
-        outs[mode] = [done[u] for u in uids]
-        if mode == "paged":
-            assert engine.metrics.gauge(
-                "serve.decode_kv_slots").value == kv_slots
-            engine.kv.allocator.check()
-    for got, want in zip(outs["paged"], outs["dense"]):
-        np.testing.assert_array_equal(got, want)
+    engine = ServeEngine(params, cfg, _registry(cfg, adapters),
+                         max_batch=3, max_seq=16, page_size=4,
+                         num_pages=num_pages, prefill_chunk=4)
+    uids = [engine.submit(prompts[i][:n], f"client{i % len(RANKS)}",
+                          max_new_tokens=m)
+            for i, (n, m) in enumerate(mix)]
+    done = engine.run()
+    assert engine.metrics.gauge("serve.decode_kv_slots").value == kv_slots
+    engine.kv.allocator.check()
+    for i, (n, m) in enumerate(mix):
+        want = merged_greedy(params, cfg, prompts[i][:n],
+                             adapters[f"client{i % len(RANKS)}"], m)
+        np.testing.assert_array_equal(done[uids[i]], want)
 
 
 @pytest.mark.parametrize("use_pallas,kv_slots", [(False, 257 * 16),
@@ -360,35 +349,37 @@ def test_decode_kv_slots_at_phi3_serving_shape(setup, use_pallas,
     assert engine.decode_kv_slots == kv_slots
 
 
-# ---------------------------------------------------------------------------
-# Dense-ring fallback regression (the PR-3 satellite bugfix)
-# ---------------------------------------------------------------------------
+def test_kv_cache_bytes_follow_the_pool_size(setup):
+    """``kv_cache_bytes`` is the pool's K and V over every layer, its
+    trash page included: a pool sized to traffic holds fewer bytes than
+    the default worst case of ``max_batch`` rows at ``max_seq``."""
+    cfg, params, adapters, _ = setup
+    per_page = (2 * cfg.num_layers * 4 * cfg.num_kv_heads
+                * cfg.resolved_head_dim * 4)       # K+V, 4 slots, f32
+    sized = {}
+    for num_pages in (None, 6):
+        engine = ServeEngine(params, cfg, _registry(cfg, adapters),
+                             max_batch=4, max_seq=16, page_size=4,
+                             num_pages=num_pages)
+        sized[num_pages] = engine.kv_cache_bytes()
+    assert sized[None] == (4 * 4 + 1) * per_page
+    assert sized[6] == (6 + 1) * per_page < sized[None]
 
-def test_dense_ring_overflow_raises_not_corrupts(setup):
-    """A row driven past its ring must fail loudly. The seed engine
-    silently wrapped ``pos % slots``, overwriting the oldest live slots
-    while the validity mask still reported them current."""
-    cfg, params, adapters, prompts = setup
+
+def test_single_token_requests_finish_at_prefill(setup):
+    """A long prompt asking for one token finishes inside admission: its
+    chunked prefill (four 4-slot pages, two 8-token chunks) yields the
+    oracle's first token, and no decode step is dispatched."""
+    cfg, params, adapters, _ = setup
+    prompt = np.asarray(jax.random.randint(
+        jax.random.PRNGKey(7), (15,), 3, cfg.vocab_size))
     engine = ServeEngine(params, cfg, _registry(cfg, adapters),
-                         max_batch=1, max_seq=8, kv_mode="dense")
-    uid = engine.submit(prompts[0][:4], "client0", max_new_tokens=4)
-    # bypass submit's guard, as a scheduler bug or future code path might
-    engine._queue[0]["max_new"] = 10
-    with pytest.raises(RuntimeError, match="ring"):
-        engine.run()
-    del uid
-
-
-def test_dense_insert_drops_out_of_range_writes():
-    """The traced insert itself fails safe: an out-of-range position
-    leaves the cache bit-identical instead of wrapping onto slot 0."""
-    from repro.serve.engine import _cache_insert_rows
-    lc = {"k": jax.numpy.ones((2, 4, 1, 8)),
-          "v": jax.numpy.ones((2, 4, 1, 8)),
-          "pos": jax.numpy.zeros((2, 4), jax.numpy.int32)}
-    k_new = jax.numpy.full((2, 1, 1, 8), 7.0)
-    out = _cache_insert_rows(lc, k_new, k_new,
-                             jax.numpy.asarray([5, 9], jax.numpy.int32))
-    np.testing.assert_array_equal(np.asarray(out["k"]), np.asarray(lc["k"]))
-    np.testing.assert_array_equal(np.asarray(out["pos"]),
-                                  np.asarray(lc["pos"]))
+                         max_batch=2, max_seq=16, page_size=4,
+                         prefill_chunk=8)
+    uids = [engine.submit(prompt, f"client{i}", max_new_tokens=1)
+            for i in range(2)]
+    outs = engine.run()
+    assert engine.steps == 0 and engine.prefill_calls == 4
+    for i, uid in enumerate(uids):
+        want = merged_greedy(params, cfg, prompt, adapters[f"client{i}"], 1)
+        np.testing.assert_array_equal(outs[uid], want)

@@ -258,6 +258,24 @@ def test_downlink_bytes_rank_truncated(cfg, base):
     assert (sizes[0] - head_b) < 0.3 * (sizes[1] - head_b)
 
 
+def test_cohort_downlink_random_ranks_below_uniform(cfg, base):
+    """Measured on the cohort broadcast: a cohort at random ranks in
+    [2, 8] is sent fewer serialized bytes per client than the same
+    cohort at a uniform r8."""
+    cohort = np.arange(6)
+    per_client = {}
+    for policy in ("uniform", "random"):
+        scfg = ServerConfig(num_clients=6, clients_per_round=6,
+                            strategy="hlora", rank_policy=policy,
+                            r_min=2, r_max=8, seed=0)
+        sess = FedSession(cfg, scfg, base, client_sizes=[64] * 6)
+        sess.broadcast_cohort(cohort)
+        assert len(sess.comm_log["downlink"]) == 1
+        per_client[policy] = sess.comm_log["downlink"][-1] / len(cohort)
+    assert (sess.ranks < 8).any()
+    assert 0 < per_client["random"] < per_client["uniform"]
+
+
 def _bits_equal(got, want, what):
     got, want = np.asarray(got), np.asarray(want)
     assert (got.dtype, got.shape) == (want.dtype, want.shape), what

@@ -1,5 +1,4 @@
-"""Sharding rules: divisibility fitting + spec structure (host-side; the
-real 512-device check is launch/dryrun.py)."""
+"""Sharding rules: divisibility fitting + spec structure (host-side)."""
 import jax
 import jax.numpy as jnp
 import numpy as np

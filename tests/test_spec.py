@@ -205,10 +205,14 @@ def test_spec_pallas_kernels_interpret(setup):
 
 
 def test_drafter_requires_paged_mode(setup):
+    """The page pool is the engine's one KV cache: any other ``kv_mode``
+    is refused, with a drafter or without."""
     cfg, params, adapters, _, _ = setup
-    with pytest.raises(ValueError, match="paged"):
-        ServeEngine(params, cfg, _registry(cfg, adapters),
-                    kv_mode="dense", drafter=NGramDrafter())
+    for kv_mode in ("dense", "ring", ""):
+        for drafter in (NGramDrafter(), None):
+            with pytest.raises(ValueError, match="paged"):
+                ServeEngine(params, cfg, _registry(cfg, adapters),
+                            kv_mode=kv_mode, drafter=drafter)
 
 
 # ---------------------------------------------------------------------------

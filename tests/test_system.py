@@ -123,80 +123,35 @@ def test_bench_quick_smoke_all_sections(tmp_path):
     import sys
     sys.path.insert(0, os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
+    from benchmarks.bench_convergence import STRATEGIES
     from benchmarks.run import ALL, main
     out = str(tmp_path / "bench.json")
-    rc = main(["--quick", "--out", out,
-               "--dryrun-jsonl", str(tmp_path / "missing.jsonl")])
+    rc = main(["--quick", "--out", out])
     got = json.load(open(out))
     assert rc == 0, got.get("_errors")
-    assert set(ALL) <= set(got), sorted(set(ALL) - set(got))
-    # the speculative serving section reports the new metrics; the
-    # exactness/acceptance asserts are deterministic — the speedup is
-    # wall-clock on a noisy box, so only its presence is tier-1
-    assert got["serve"]["spec_forced_exact"] == 1.0
-    assert got["serve"]["spec_forced_acceptance"] == 1.0
-    assert got["serve"]["spec_forced_speedup_vs_plain"] > 0
-    # the mesh-scaling subsections run in forced-host-device children;
-    # equivalence (bit-identity / byte-exactness vs single-device) is
-    # deterministic and pinned — the speedups are wall-clock, presence
-    # only
-    assert got["fed"]["mesh_agg_bit_identical"] == 1
-    assert got["fed"]["mesh_agg_speedup"] > 0
-    assert got["serve"]["mesh_scaling_exact"] == 1.0
-    assert got["serve"]["mesh_traces_flat"] == 1
-    assert got["serve"]["mesh_tok_per_s_sharded"] > 0
-    # the observability section: trace export validated, JSONL round-
-    # tripped, and the promised span names present (all deterministic);
-    # recorder-derived latency percentiles are wall-clock, presence only
-    assert got["obs"]["obs_jsonl_roundtrip"] == 1
-    assert got["obs"]["obs_span_names_ok"] == 1
-    assert got["obs"]["obs_events"] > 0 and got["obs"]["obs_tracks"] > 0
-    assert got["serve"]["obs_ttft_p99_ms"] > 0
-    assert got["serve"]["obs_req_tok_s_p50"] > 0
-    assert got["fed"]["obs_round_ms_p50"] > 0
-    assert got["fed"]["obs_downlink_bytes_per_round"] > 0
-    # the watching layer (PR 8): SLOs evaluate clean over the smoke
-    # run, the HTML ops report renders non-empty, and the mesh child's
-    # events were collected, clock-rebased, and merged into a trace
-    # that validates
-    assert got["obs"]["obs_slo_ok"] == 1
-    assert got["obs"]["obs_series"] > 0
-    assert got["obs"]["obs_report_bytes"] > 0
-    assert got["obs"]["obs_child_events"] > 0
-    assert got["obs"]["obs_merged_valid"] == 1
-    assert got["obs"]["obs_merged_events"] > got["obs"]["obs_child_events"]
-    # per-class TTFT SLO attainment (generous targets: deterministic)
-    assert got["serve"]["obs_slo_interactive_attainment"] == 1.0
-    assert got["serve"]["obs_slo_batch_attainment"] == 1.0
-    assert got["serve"]["obs_slo_interactive_total"] > 0
-    # hierarchical two-tier aggregation: stack mode is pinned bit-identical
-    # to flat, and the edge->root tier carries measured wire bytes
-    assert got["fed"]["hier_bit_identical"] == 1
-    assert got["fed"]["hier_edge_uplink_bytes_per_round"] > 0
-    assert got["fed"]["hier_engine_edge_bytes_per_round"] > 0
-    # population-scale round: lazy materialization never exceeds cohort
-    assert got["fed"]["pop_clients"] >= 2000
-    assert got["fed"]["pop_max_resident"] <= got["fed"]["pop_cohort"]
-    assert got["fed"]["pop_uplink_bytes_per_round"] > 0
+    assert set(got) == set(ALL) == {"convergence", "bias", "comm"}
+    # Fig. 3 / Table 1: every strategy row trains and lands an accuracy
+    for _, _, label in STRATEGIES:
+        row = got["convergence"][f"mrpc/{label}"]
+        assert len(row["curve"]) >= 1
+        assert 0.0 <= row["final"] <= 1.0 and 0.0 <= row["best"] <= 1.0
+    # Eq. 1: the measured naive-averaging bias is a finite, positive
+    # relative error
+    assert 0.0 < got["bias"]["2"] < float("inf")
+    # measured wire bytes: heterogeneous ranks ship less than uniform r8,
+    # and the engine's redistributed factors carry live bytes
+    assert got["comm"]["random_2_8"] < got["comm"]["uniform_r8"]
+    assert got["comm"]["engine_measured_random_2_8"] > 0
     # wire codec curve: none is exact, quantized/truncated curves are
     # strictly cheaper than raw f32 (deterministic byte counts)
     assert got["comm"]["codec_none_rel_err"] == 0.0
     assert got["comm"]["codec_int8_bytes"] < got["comm"]["codec_bf16_bytes"]
     assert got["comm"]["codec_bf16_bytes"] < got["comm"]["codec_none_bytes"]
     assert got["comm"]["codec_topk2_bytes"] < got["comm"]["codec_none_bytes"]
-    # the invariant lint suite ran through its real CLI entry point:
-    # the pass registry lists all >=5 rules and the shipped tree is
-    # clean (both deterministic — a broken registry import or a new
-    # un-pragma'd violation fails the smoke run here)
-    assert got["analysis"]["rules_listed"] >= 5
-    assert got["analysis"]["cli_list_rc"] == 0
-    assert got["analysis"]["tree_clean"] == 1
-    # every invocation appends to the perf history beside --out
-    hist = str(tmp_path / "bench_history.jsonl")
-    assert os.path.exists(hist)
-    entries = [json.loads(l) for l in open(hist) if l.strip()]
-    assert len(entries) == 1 and entries[0]["quick"] is True
-    assert "serve.engine_tok_per_s" in entries[0]["results"]
+    # the runner measures no speed: a Table 1 row holds accuracies only
+    assert all(set(row) == {"curve", "final", "best", "mean_last3",
+                            "rounds_to_66"}
+               for row in got["convergence"].values())
 
 
 def test_bench_merge_preserves_sections_on_failure(tmp_path):
@@ -208,117 +163,18 @@ def test_bench_merge_preserves_sections_on_failure(tmp_path):
         os.path.abspath(__file__))))
     from benchmarks.run import merge_results
     path = str(tmp_path / "bench.json")
-    merge_results(path, {"serve": {"x": 1}, "svd": {"y": 2}}, {})
-    merge_results(path, {"svd": {"y": 3}}, {"serve": "RuntimeError: boom"})
+    merge_results(path, {"convergence": {"x": 1}, "bias": {"y": 2}}, {})
+    merge_results(path, {"bias": {"y": 3}},
+                  {"convergence": "RuntimeError: boom"})
     got = json.load(open(path))
-    assert got["serve"] == {"x": 1}          # old numbers survive
-    assert got["svd"] == {"y": 3}            # re-run section updated
-    assert got["_errors"] == {"serve": "RuntimeError: boom"}
-    merge_results(path, {"serve": {"x": 9}}, {})
+    assert got["convergence"] == {"x": 1}    # old numbers survive
+    assert got["bias"] == {"y": 3}           # re-run section updated
+    assert got["_errors"] == {"convergence": "RuntimeError: boom"}
+    merge_results(path, {"convergence": {"x": 9}}, {})
     got = json.load(open(path))
-    assert got["serve"] == {"x": 9} and "_errors" not in got
+    assert got["convergence"] == {"x": 9} and "_errors" not in got
     # corrupt previous file: start fresh instead of crashing
     with open(path, "w") as f:
         f.write("{not json")
     merge_results(path, {"comm": {"z": 1}}, {})
     assert json.load(open(path)) == {"comm": {"z": 1}}
-
-
-def test_bench_regression_gate(tmp_path):
-    """The perf-regression gate at unit level: identical back-to-back
-    runs pass, a >20% move in the bad direction on a curated key fails,
-    a within-threshold move passes, and keys missing from either run
-    are skipped (new benches don't break the gate)."""
-    import sys
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    from benchmarks.run import (QUICK_REGRESSION_THRESHOLD,
-                                REGRESSION_KEYS, REGRESSION_THRESHOLD,
-                                append_history, check_regressions,
-                                flatten_numeric, history_path_for)
-    base = {"serve.engine_tok_per_s": 1000.0,
-            "serve.obs_ttft_p99_ms": 10.0,
-            "fed.obs_round_ms_p99": 200.0}
-    # identical back-to-back: clean
-    assert check_regressions(base, dict(base)) == []
-    # within threshold (15% either way): clean
-    ok = {"serve.engine_tok_per_s": 850.0,     # -15%, higher-is-better
-          "serve.obs_ttft_p99_ms": 11.5,       # +15%, lower-is-better
-          "fed.obs_round_ms_p99": 200.0}
-    assert check_regressions(base, ok) == []
-    # injected regressions: throughput -30%, latency +50%
-    bad = {"serve.engine_tok_per_s": 700.0,
-           "serve.obs_ttft_p99_ms": 15.0,
-           "fed.obs_round_ms_p99": 200.0}
-    hits = check_regressions(base, bad)
-    assert {h[0] for h in hits} == {"serve.engine_tok_per_s",
-                                    "serve.obs_ttft_p99_ms"}
-    # IMPROVEMENTS never trip the gate (direction-aware)
-    better = {"serve.engine_tok_per_s": 5000.0,
-              "serve.obs_ttft_p99_ms": 1.0,
-              "fed.obs_round_ms_p99": 50.0}
-    assert check_regressions(base, better) == []
-    # missing keys (either side) and zero/negative baselines: skipped
-    assert check_regressions({}, bad) == []
-    assert check_regressions({"serve.engine_tok_per_s": 0.0},
-                             {"serve.engine_tok_per_s": 1.0}) == []
-    # mesh keys are deliberately NOT gated (host-device artifacts)
-    assert not any(k.startswith(("serve.mesh_", "fed.mesh_"))
-                   for k in REGRESSION_KEYS)
-    assert REGRESSION_THRESHOLD == pytest.approx(0.20)
-    # quick smoke shapes jitter ~±30% wall-clock, so quick mode gates
-    # wider — still far under the 2-10x moves a real perf rot produces
-    assert QUICK_REGRESSION_THRESHOLD > REGRESSION_THRESHOLD
-    bad30 = {"serve.engine_tok_per_s": 700.0}   # -30%: noise at --quick
-    assert check_regressions(base, bad30,
-                             threshold=QUICK_REGRESSION_THRESHOLD) == []
-    bad60 = {"serve.engine_tok_per_s": 400.0}   # -60%: rot in any mode
-    assert len(check_regressions(base, bad60,
-                                 threshold=QUICK_REGRESSION_THRESHOLD)) == 1
-
-    # flatten drops private keys, non-numerics, bools, non-dict
-    # sections (roofline rows), and non-str keys (convergence sub-dicts
-    # keyed by int rank)
-    flat = flatten_numeric({"serve": {"a": 1, "_p": 2, "s": "x",
-                                      "b": True},
-                            "convergence": {4: {"acc": 0.9}, "n": 2},
-                            "roofline": [{"gflops": 1.0}],
-                            "_errors": {"x": "y"}})
-    assert flat == {"serve.a": 1.0, "convergence.n": 2.0}
-
-    # history: same-mode previous entry is returned, modes are disjoint
-    hp = str(tmp_path / "h.jsonl")
-    assert append_history(hp, {"k": 1.0}, quick=True) is None
-    assert append_history(hp, {"k": 2.0}, quick=False) is None
-    prev = append_history(hp, {"k": 3.0}, quick=True)
-    assert prev["results"] == {"k": 1.0}
-    assert len([l for l in open(hp) if l.strip()]) == 3
-    # torn trailing line (crashed writer) is dropped, not fatal
-    with open(hp, "a") as f:
-        f.write("{torn")
-    prev = append_history(hp, {"k": 4.0}, quick=True)
-    assert prev["results"] == {"k": 3.0}
-
-    assert history_path_for("results/bench_results.json") == \
-        os.path.join("results", "bench_history.jsonl")
-    assert history_path_for(str(tmp_path / "bench_quick.json")) == \
-        str(tmp_path / "bench_quick_history.jsonl")
-
-
-def test_bench_check_flag_fails_on_injected_regression(tmp_path):
-    """--check end-to-end through main() without running real benches:
-    seed the history with a strong previous entry, run only the cheap
-    ``comm`` section, and verify rc. Since comm has no curated keys,
-    the gate passes vacuously; then inject a history where the current
-    run WOULD regress by pre-seeding overlapping keys via a fake
-    section result written through append_history + check directly."""
-    import sys
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    from benchmarks.run import check_regressions
-    # the rc=2 path is main()'s only logic on top of check_regressions;
-    # exercise the decision table here (running two full --quick passes
-    # back-to-back in tier-1 would double suite time for no new signal)
-    prev = {"serve.engine_tok_per_s": 1000.0}
-    assert check_regressions(prev, {"serve.engine_tok_per_s": 799.0})
-    assert not check_regressions(prev, {"serve.engine_tok_per_s": 801.0})
